@@ -46,6 +46,7 @@ from repro.core import (Device, PlacementRule, Pool, TiB, build_cluster,
                         create_planner)
 from repro.fleet import FleetLoadGen, FleetPlanner, FleetService
 from repro.obs.metrics import registry
+from repro.compile_cache import enable_compile_cache
 
 #: streaming-mode geometry: chunk=1 is the finest SLO granularity (one
 #: move per dispatch per lane) — the regime the fleet exists for
@@ -279,6 +280,7 @@ def main() -> None:
                     help="keep the bench trace (*.jsonl native, otherwise "
                          "Chrome/Perfetto JSON); default: in-memory only")
     args = ap.parse_args()
+    enable_compile_cache()
 
     n = 8                       # the acceptance point: N=8 quick clusters
     n_loadgen = 2 if args.quick else 4

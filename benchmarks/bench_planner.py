@@ -51,6 +51,7 @@ from repro.core import EquilibriumConfig, create_planner
 from repro.core.clustergen import cluster_b
 from repro.core.equilibrium_batch import DONATED_CARRY
 from repro.core.equilibrium_jax import DenseState, _jax_select
+from repro.compile_cache import enable_compile_cache
 
 import jax.numpy as jnp
 
@@ -293,41 +294,63 @@ def bench_tail(initial, tag: str, warm: int) -> list[dict]:
     return rows
 
 
+def _shard_profile_subprocess(repo: str, n: int, scale: int, budget: int,
+                              cache: str | None,
+                              trace_dir: str | None) -> dict:
+    """One CPU mesh point: ``tools/shard_profile.py`` on ``n`` forced
+    host devices; returns its JSON result."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n} "
+                        + env.get("XLA_FLAGS", "")).strip()
+    env.pop("PYTHONPATH", None)
+    cmd = [sys.executable, os.path.join(repo, "tools", "shard_profile.py"),
+           "--devices", str(n), "--scale", str(scale),
+           "--budget", str(budget)]
+    if cache:
+        cmd += ["--cache", cache]
+    if trace_dir:
+        cmd += ["--trace-out", os.path.join(trace_dir, f"shard_n{n}.jsonl")]
+    proc = subprocess.run(cmd, capture_output=True, text=True, env=env,
+                          cwd=repo)
+    if proc.returncode != 0:
+        raise RuntimeError(f"shard_profile --devices {n} failed:\n"
+                           f"{proc.stderr[-4000:]}")
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
 def bench_shards(devices, scale: int, budget: int, cache: str | None,
                  trace_dir: str | None = None) -> list[dict]:
-    """Sharded-planner profile rows, one subprocess per mesh size.
+    """Sharded-planner profile rows, one per mesh size.
 
-    JAX fixes the host device count at process start, so each mesh point
-    spawns ``tools/shard_profile.py`` under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` and the rows
-    are stitched from the workers' JSON lines.  The N=1 point anchors
-    ``peak_ratio_vs_n1`` — the per-device peak memory of the compiled
-    chunk program, whose ~1/N scaling is the scale-out claim.  The
-    cluster build is pickle-cached and shared across mesh sizes."""
+    On a TPU host every mesh point runs in this process on
+    ``jax.devices()[:N]`` (a chip belongs to one process, and this one
+    already holds it).  On a CPU host JAX fixes the device count at
+    process start, so each mesh point spawns ``tools/shard_profile.py``
+    under ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` and the
+    rows are stitched from the workers' JSON lines.  The N=1 point
+    anchors ``peak_ratio_vs_n1`` — the per-device peak memory of the
+    compiled chunk program, whose ~1/N scaling is the scale-out claim.
+    The cluster build is pickle-cached and shared across mesh sizes."""
+    import jax
     sha = git_sha()
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if trace_dir:
+        os.makedirs(trace_dir, exist_ok=True)
+    on_tpu = jax.default_backend() == "tpu"
+    if on_tpu:
+        sys.path.insert(0, os.path.join(repo, "tools"))
+        from shard_profile import load_state, profile_point
+        state = load_state(scale, cache)[0]
     rows, base_peak = [], None
     for n in devices:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n} "
-                            + env.get("XLA_FLAGS", "")).strip()
-        env.pop("PYTHONPATH", None)
-        cmd = [sys.executable,
-               os.path.join(repo, "tools", "shard_profile.py"),
-               "--devices", str(n), "--scale", str(scale),
-               "--budget", str(budget)]
-        if cache:
-            cmd += ["--cache", cache]
-        if trace_dir:
-            os.makedirs(trace_dir, exist_ok=True)
-            cmd += ["--trace-out",
-                    os.path.join(trace_dir, f"shard_n{n}.jsonl")]
-        proc = subprocess.run(cmd, capture_output=True, text=True,
-                              env=env, cwd=repo)
-        if proc.returncode != 0:
-            raise RuntimeError(f"shard_profile --devices {n} failed:\n"
-                               f"{proc.stderr[-4000:]}")
-        res = json.loads(proc.stdout.strip().splitlines()[-1])
+        if on_tpu:
+            res = profile_point(
+                state, n, budget,
+                trace_out=(os.path.join(trace_dir, f"shard_n{n}.jsonl")
+                           if trace_dir else None))
+        else:
+            res = _shard_profile_subprocess(repo, n, scale, budget, cache,
+                                            trace_dir)
         peak = int(res["peak_bytes_per_device"])
         if base_peak is None:
             base_peak = max(peak, 1)
@@ -373,6 +396,7 @@ def main() -> None:
                     help="keep per-worker shard traces here (feeds "
                          "tools/tracestat.py --shards)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     shard_devices = [int(x) for x in args.shard_devices.split(",") if x]
     shard_cache = args.shard_cache or os.path.join(

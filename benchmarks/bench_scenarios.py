@@ -44,6 +44,7 @@ from benchmarks.run import git_sha
 from repro import obs
 from repro.core import TiB, available_planners
 from repro.sim import SCENARIOS, run_scenario
+from repro.compile_cache import enable_compile_cache
 
 DEFAULT_BALANCERS = ("equilibrium_batch", "mgr")
 
@@ -130,6 +131,7 @@ def main() -> None:
                     help="keep the bench trace (*.jsonl native, otherwise "
                          "Chrome/Perfetto JSON); default: in-memory only")
     args = ap.parse_args()
+    enable_compile_cache()
     balancers = tuple(b for b in args.balancers.split(",") if b)
     for b in balancers:
         if b not in available_planners():

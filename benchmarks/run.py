@@ -32,6 +32,8 @@ import subprocess
 import sys
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 
 def git_sha() -> str:
     try:
@@ -63,6 +65,7 @@ def main() -> None:
                          "(repro.obs): *.jsonl gets the native line format, "
                          "any other suffix a Chrome/Perfetto trace JSON")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.json and args.scenarios and \
             os.path.abspath(args.json) == os.path.abspath(args.scenarios_out):

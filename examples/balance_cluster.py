@@ -9,6 +9,7 @@ import csv
 
 from repro.core import (EquilibriumConfig, MgrBalancerConfig, PAPER_CLUSTERS,
                         TiB, create_planner, simulate)
+from repro.compile_cache import enable_compile_cache
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--cluster", choices=sorted(PAPER_CLUSTERS), default="A")
@@ -23,6 +24,7 @@ ap.add_argument("--engine", default="equilibrium",
                      "all bit-identical")
 ap.add_argument("--trajectory-csv", default=None)
 args = ap.parse_args()
+enable_compile_cache()
 
 initial = PAPER_CLUSTERS[args.cluster]()
 print(f"cluster {args.cluster}: {initial.n_devices} OSDs, "

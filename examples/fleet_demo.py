@@ -17,6 +17,7 @@ import numpy as np
 
 from repro import obs
 from repro.core import Device, PlacementRule, Pool, TiB, build_cluster
+from repro.compile_cache import enable_compile_cache
 
 GiB = TiB / 1024
 
@@ -48,6 +49,7 @@ def main() -> None:
                     help="write the run's repro.obs trace for "
                          "tools/tracestat.py --fleet")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.fleet import FleetService    # after CLI: imports touch jax
 

@@ -8,9 +8,11 @@ volume, and utilization variance (Table-1-style row).
 """
 
 from repro import obs
+from repro.compile_cache import enable_compile_cache
 from repro.core import (EquilibriumConfig, MgrBalancerConfig, TiB,
                         create_planner, simulate, small_test_cluster)
 
+enable_compile_cache()
 initial = small_test_cluster()
 print(f"cluster: {initial.n_devices} OSDs, {len(initial.acting)} PGs, "
       f"utilization {initial.utilization().min():.2f}"

@@ -14,6 +14,7 @@ import argparse
 from repro import obs
 from repro.core import TiB, available_planners
 from repro.sim import SCENARIOS, run_scenario
+from repro.compile_cache import enable_compile_cache
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--scenario", choices=sorted(SCENARIOS),
@@ -28,6 +29,7 @@ ap.add_argument("--trace-out", default=None, metavar="PATH",
                 help="write the run's repro.obs trace (*.jsonl native, "
                      "otherwise Chrome/Perfetto JSON for tools/tracestat.py)")
 args = ap.parse_args()
+enable_compile_cache()
 
 print(f"scenario {args.scenario!r} ({SCENARIOS[args.scenario].description})")
 # the run is traced (in-memory unless --trace-out): every tick and plan

@@ -18,34 +18,64 @@ import numpy as np
 from .cluster import ClusterState, Device, PGId, Pool, RuleStep
 
 
-def _select_step(rng: np.random.Generator, devices: Sequence[Device],
-                 step: RuleStep, taken_osds: set[int],
-                 taken_domains: set[str]) -> list[int]:
+class _DeviceTable:
+    """Array views of a device list, per (device class, failure-domain
+    level): device ids and capacities in list order, and each device's
+    failure-domain token as a small int.  Built once per placement run,
+    so a draw filters arrays instead of walking Device objects."""
+
+    def __init__(self, devices: Sequence[Device]):
+        self.devices = devices
+        self._views: dict = {}
+
+    def view(self, device_class: str | None, level: str):
+        key = (device_class, level)
+        if key not in self._views:
+            devs = [d for d in self.devices
+                    if device_class is None or d.device_class == device_class]
+            tokens: dict[str, int] = {}
+            self._views[key] = (
+                np.array([d.id for d in devs], dtype=np.int64),
+                np.array([d.capacity for d in devs], dtype=np.float64),
+                np.array([tokens.setdefault(d.domain(level), len(tokens))
+                          for d in devs], dtype=np.int64),
+                len(tokens))
+        return self._views[key]
+
+
+def _select_step(rng: np.random.Generator, table: _DeviceTable,
+                 step: RuleStep, taken_osds: set[int]) -> list[int]:
     """Pick ``step.count`` devices for one rule step: capacity-weighted
     draws without replacement, one per failure domain."""
+    ids, caps, dom, n_dom = table.view(step.device_class,
+                                       step.failure_domain)
+    free = ~np.isin(ids, list(taken_osds))
+    dom_taken = np.zeros(n_dom, dtype=bool)
     chosen: list[int] = []
-    domains = set(taken_domains)
-    pool_devs = [d for d in devices
-                 if (step.device_class is None or d.device_class == step.device_class)]
     for _ in range(step.count):
-        cands = [d for d in pool_devs
-                 if d.id not in taken_osds and d.domain(step.failure_domain) not in domains]
-        if not cands:
+        cands = np.flatnonzero(free & ~dom_taken[dom])
+        if not cands.size:
             raise RuntimeError(
                 f"cannot satisfy rule step {step}: no candidate device left "
-                f"(domains taken: {len(domains)})")
-        weights = np.array([d.capacity for d in cands], dtype=np.float64)
+                f"(domains taken: {int(dom_taken.sum())})")
+        weights = caps[cands]
         weights /= weights.sum()
-        pick = cands[int(rng.choice(len(cands), p=weights))]
-        chosen.append(pick.id)
-        taken_osds.add(pick.id)
-        domains.add(pick.domain(step.failure_domain))
+        j = cands[int(rng.choice(len(cands), p=weights))]
+        chosen.append(int(ids[j]))
+        taken_osds.add(int(ids[j]))
+        free[j] = False
+        dom_taken[dom[j]] = True
     return chosen
 
 
 def place_pg(devices: Sequence[Device], pool: Pool, pg_index: int,
              seed: int = 0) -> list[int]:
     """Place all shards of one PG (deterministic in (seed, pool, pg))."""
+    return _place(_DeviceTable(devices), pool, pg_index, seed)
+
+
+def _place(table: _DeviceTable, pool: Pool, pg_index: int,
+           seed: int) -> list[int]:
     rng = np.random.default_rng((seed, pool.id, pg_index))
     taken_osds: set[int] = set()
     acting: list[int] = []
@@ -53,7 +83,7 @@ def place_pg(devices: Sequence[Device], pool: Pool, pg_index: int,
         # Failure-domain separation applies within a rule step; Ceph hybrid
         # rules (e.g. 1×ssd + 2×hdd) allow ssd and hdd shards to share a
         # host, matching per-step `take` semantics.
-        acting += _select_step(rng, devices, step, taken_osds, set())
+        acting += _select_step(rng, table, step, taken_osds)
     return acting
 
 
@@ -68,11 +98,12 @@ def build_cluster(devices: Sequence[Device], pools: Sequence[Pool],
     acting: dict[PGId, list[int]] = {}
     shard_sizes: dict[PGId, float] = {}
     rng = np.random.default_rng((seed, 0xC0FFEE))
+    table = _DeviceTable(devices)
     for pool in pools:
         nominal = pool.nominal_shard_size
         for pg in range(pool.pg_count):
             pgid: PGId = (pool.id, pg)
-            acting[pgid] = place_pg(devices, pool, pg, seed=seed)
+            acting[pgid] = _place(table, pool, pg, seed)
             jitter = float(rng.normal(1.0, size_jitter)) if nominal > 0 else 0.0
             shard_sizes[pgid] = max(nominal * max(jitter, 0.1), 0.0)
     state = ClusterState(devices, pools, acting, shard_sizes)

@@ -32,9 +32,10 @@ blocking ``bool(found)`` device sync per source.  This module moves the
   small tile because the fullest source almost always yields the move —
   same move sequence either way, property-tested across tile shapes.
 * **The inner masked-argmax/argmin reduction is a kernel** —
-  :func:`repro.kernels.ops.masked_select` (Pallas on TPU, interpret-mode
-  fallback, pure-jnp reference on CPU), returning per candidate row
-  whether any destination is legal and the emptiest legal destination.
+  :func:`repro.kernels.ops.masked_select` (Pallas, compiled on a TPU
+  over an exact int32 order key; the pure-jnp reference on CPU),
+  returning per candidate row whether any destination is legal and the
+  emptiest legal destination.
 * **Moves apply functionally on-device.**  A ``lax.scan`` emits up to
   ``chunk`` moves per host round-trip; each applied move updates the
   carry with masked scatters (masked, not branched — ``lax.cond`` around
@@ -47,7 +48,7 @@ blocking ``bool(found)`` device sync per source.  This module moves the
   source assignment), exactly like :func:`repro.core.simulate.simulate`
   replays movement logs.
 
-All float math runs in float64 (``jax.experimental.enable_x64``) with the
+All float math runs in float64 (``jax.enable_x64(True)``) with the
 same expressions and evaluation order as the NumPy engine, so the move
 sequences are **bit-identical** to the faithful §3.1 planner — property-
 tested across multi-pool / multi-class / hybrid-rule clusters in
@@ -65,6 +66,10 @@ from functools import partial
 
 import numpy as np
 
+import jax
+import jax.numpy as jnp
+from jax import lax
+
 from . import legality
 from .cluster import (ClusterDelta, ClusterState, DeviceAddDelta,
                       DeviceOutDelta, Movement, MovementDelta,
@@ -73,17 +78,8 @@ from .equilibrium import EquilibriumConfig, MoveRecord
 from .legality import LegalityState
 from .tail import tail_flush, tail_record, tail_stats, tail_terminal
 from .. import obs as _obs
+from ..kernels.select_move import compact_parked, order_key
 from ..obs import registry as _obs_registry
-
-try:  # pragma: no cover - JAX is always present in this repo
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import enable_x64
-    from ..kernels.select_move import compact_parked
-    _HAVE_JAX = True
-except Exception:  # pragma: no cover
-    _HAVE_JAX = False
 
 
 def host_sync_count() -> int:
@@ -107,14 +103,18 @@ def _fetch(tree):
     return jax.device_get(tree)
 
 
-def _select_rows(valid2d, util, backend: str):
+def _select_rows(valid2d, util, backend: str, key=None):
     """Dispatch the masked-select reduction: per candidate row, any-legal
-    flag and emptiest legal destination (ties → lowest device index)."""
+    flag and emptiest legal destination (ties → lowest device index).
+    The Pallas backends reduce ``key``, the exact int32 order key of
+    ``util`` (:func:`repro.kernels.select_move.order_key`); "pallas-tpu"
+    compiles the kernel with Mosaic and "pallas" interprets it (a host
+    without a TPU only — :class:`BatchPlanner` refuses it on a TPU)."""
     if backend == "ref":
         from ..kernels.ref import masked_select_ref
         return masked_select_ref(valid2d, util)
     from ..kernels.ops import masked_select
-    return masked_select(valid2d, util, interpret=(backend != "pallas-tpu"))
+    return masked_select(valid2d, key, interpret=(backend == "pallas"))
 
 
 def _shift_remove(arr, pos, pad):
@@ -225,6 +225,9 @@ def _plan_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, k_eff,
         used, util, us, usq, acting, pool_counts, dst_ok, \
             rows_on, nrows, order, c_dev, c_ok, c_clean, pruned = dyn
         order_k = order[:k]         # maintained == argsort(-util, stable)
+        # the Pallas select reduces an exact int32 key of util, derived
+        # from the maintained order once per step
+        key = None if backend == "ref" else order_key(util, order)
         if bounds:
             # persistent priority queue: unpruned sources first (faithful
             # fullest-first order preserved), pruned sources parked at
@@ -374,7 +377,7 @@ def _plan_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, k_eff,
                 jnp.any(any_rows),
                 lambda t: _select_rows(
                     (t & eval_var(sb, c)).reshape(kb * rb, n_dev),
-                    util, backend),
+                    util, backend, key),
                 lambda t: (jnp.zeros((kb * rb,), bool),
                            jnp.zeros((kb * rb,), jnp.int32)),
                 cand)
@@ -751,9 +754,17 @@ class BatchPlanner:
         # i's host-side processing (pure scheduling — the dispatch gate in
         # _chunk_loop keeps the emitted sequence bit-identical)
         self.pipeline = pipeline
+        on_tpu = jax.default_backend() == "tpu"
         if select_backend == "auto":
-            select_backend = ("pallas-tpu" if jax.default_backend() == "tpu"
-                              else "ref")
+            select_backend = "pallas-tpu" if on_tpu else "ref"
+        if select_backend not in ("ref", "pallas", "pallas-tpu"):
+            raise ValueError(f"unknown select_backend {select_backend!r}: "
+                             "expected 'auto', 'ref', 'pallas' or "
+                             "'pallas-tpu'")
+        if select_backend == "pallas" and on_tpu:
+            raise ValueError("select_backend='pallas' interprets the kernel "
+                             "and is for hosts without a TPU; use "
+                             "'pallas-tpu' (or 'auto') on a TPU")
         self.select_backend = select_backend
         self._k = min(self.cfg.k, max(state.n_devices, 1))
         self._kb = min(max(1, source_block), self._k)
@@ -1250,8 +1261,8 @@ class BatchPlanner:
         """Bring the device carry up to date with the bound state:
         cold-build on first use, absorb an absorbable pending delta run
         into the warm carry, full rebuild as the fallback.  Callers must
-        hold ``enable_x64()`` (as :meth:`plan` and the fleet planner's
-        tick both do)."""
+        hold ``jax.enable_x64(True)`` (as :meth:`plan` and the fleet
+        planner's tick both do)."""
         if self._epoch < 0:
             self._build()
         elif self.stale and not self._absorb():
@@ -1496,7 +1507,7 @@ class BatchPlanner:
         budget = self.cfg.max_moves if max_moves is None else max_moves
         snap = (_obs_registry().snapshot() if stats_out is not None
                 else None)
-        with enable_x64():
+        with jax.enable_x64(True):
             self.sync()
             if self._dyn is None or budget <= 0:
                 if stats_out is not None:
@@ -1533,13 +1544,14 @@ def _balance_batch(state: ClusterState, cfg: EquilibriumConfig | None = None,
     tensor at once; the defaults walk it lazily because the fullest
     source usually yields the move).  ``row_capacity`` pads the
     per-device row table (default: max shards/device + ``chunk``, the
-    no-overflow invariant).  ``select_backend``: "auto" (Pallas on TPU,
-    jnp reference elsewhere), "ref", "pallas" (interpret off-TPU), or
-    "pallas-tpu".  ``legality_cache`` opts into the cross-move
-    candidate-mask cache (full tile masks kept in the carry, two columns
-    repaired per move): off by default because at the CPU tile sizes the
-    per-move repair costs more than the fresh candidate evaluation it
-    saves — it exists for accelerator geometries, and stays
+    no-overflow invariant).  ``select_backend``: "auto" (the compiled
+    Pallas kernel on a TPU, the jnp reference elsewhere), "ref",
+    "pallas" (the kernel interpreted; hosts without a TPU only), or
+    "pallas-tpu" (the kernel compiled with Mosaic).  ``legality_cache``
+    opts into the cross-move candidate-mask cache (full tile masks kept
+    in the carry, two columns repaired per move): off by default because
+    at the CPU tile sizes the per-move repair costs more than the fresh
+    candidate evaluation it saves — it exists for accelerator geometries, and stays
     property-tested bit-identical either way.  ``source_bounds`` (on by
     default) keeps per-source no-candidate certificates plus the pruned
     stable partition of the source walk; both opt-outs are benchmarked
@@ -1555,11 +1567,6 @@ def _balance_batch(state: ClusterState, cfg: EquilibriumConfig | None = None,
     the dense state (the scenario engine's warm-start path).
     """
     cfg = cfg or EquilibriumConfig()
-    if not _HAVE_JAX:  # pragma: no cover - numpy fallback, same outputs
-        from .equilibrium_jax import _balance_fast
-        return _balance_fast(state, cfg, record_trajectory=record_trajectory,
-                             record_free_space=record_free_space,
-                             engine="numpy", stats_out=stats_out)
     planner = BatchPlanner(state, cfg, chunk=chunk, source_block=source_block,
                            row_block=row_block, row_capacity=row_capacity,
                            select_backend=select_backend,

@@ -44,18 +44,13 @@ from functools import partial
 
 import numpy as np
 
+import jax
+import jax.numpy as jnp
+
 from .cluster import ClusterState, Movement
 from .equilibrium import EquilibriumConfig, MoveRecord
 from . import legality
 from .legality import LegalityState
-
-try:  # JAX is always present in this repo, but the numpy path is standalone.
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-    _HAVE_JAX = True
-except Exception:  # pragma: no cover
-    _HAVE_JAX = False
 
 
 # ---------------------------------------------------------------------------
@@ -380,45 +375,43 @@ class DenseState:
 # JAX kernel for the hot selection math
 
 
-if _HAVE_JAX:
+@partial(jax.jit, static_argnames=("n_dev",))
+def _jax_select(sizes, cls, member, peer_occ, own_dom_eq, cnt, ideal,
+                src_cnt, src_ideal, used, cap, util, util_sum, util_sumsq,
+                dev_class, src_idx, count_slack, headroom,
+                min_variance_delta, n_dev):
+    """Jitted (R, n_dev) legality+criteria evaluation and selection.
 
-    @partial(jax.jit, static_argnames=("n_dev",))
-    def _jax_select(sizes, cls, member, peer_occ, own_dom_eq, cnt, ideal,
-                    src_cnt, src_ideal, used, cap, util, util_sum, util_sumsq,
-                    dev_class, src_idx, count_slack, headroom,
-                    min_variance_delta, n_dev):
-        """Jitted (R, n_dev) legality+criteria evaluation and selection.
+    Returns (row_local_idx, dest_idx, found) — indices into the padded
+    row block.  Padded rows carry size<=0 and are masked out.
+    """
+    R = sizes.shape[0]
+    sizes_c = sizes[:, None]
+    cls_ok = legality.class_ok(cls[:, None], dev_class[None, :])
+    not_member = ~member
+    dom_ok = (peer_occ - own_dom_eq[None, :].astype(peer_occ.dtype)) <= 0
+    cap_ok = legality.capacity_ok(
+        used[None, :], legality.capacity_limit(cap[None, :], headroom),
+        sizes_c)
+    src_ok = legality.src_count_ok(src_cnt, src_ideal, count_slack)
+    dst_ok = legality.dst_count_ok(cnt, ideal, count_slack)
 
-        Returns (row_local_idx, dest_idx, found) — indices into the padded
-        row block.  Padded rows carry size<=0 and are masked out.
-        """
-        R = sizes.shape[0]
-        sizes_c = sizes[:, None]
-        cls_ok = legality.class_ok(cls[:, None], dev_class[None, :])
-        not_member = ~member
-        dom_ok = (peer_occ - own_dom_eq[None, :].astype(peer_occ.dtype)) <= 0
-        cap_ok = legality.capacity_ok(
-            used[None, :], legality.capacity_limit(cap[None, :], headroom),
-            sizes_c)
-        src_ok = legality.src_count_ok(src_cnt, src_ideal, count_slack)
-        dst_ok = legality.dst_count_ok(cnt, ideal, count_slack)
+    n_f = jnp.asarray(n_dev, sizes.dtype)
+    var_ok = legality.variance_improves(
+        used[src_idx], used[None, :], cap[src_idx], cap[None, :],
+        util[src_idx], util[None, :], sizes_c, util_sum, util_sumsq,
+        n_f, min_variance_delta)
 
-        n_f = jnp.asarray(n_dev, sizes.dtype)
-        var_ok = legality.variance_improves(
-            used[src_idx], used[None, :], cap[src_idx], cap[None, :],
-            util[src_idx], util[None, :], sizes_c, util_sum, util_sumsq,
-            n_f, min_variance_delta)
+    valid = (cls_ok & not_member & dom_ok & cap_ok & dst_ok & var_ok
+             & src_ok[:, None] & (sizes_c > 0))
+    valid = valid.at[:, src_idx].set(False)
 
-        valid = (cls_ok & not_member & dom_ok & cap_ok & dst_ok & var_ok
-                 & src_ok[:, None] & (sizes_c > 0))
-        valid = valid.at[:, src_idx].set(False)
-
-        any_valid = valid.any(axis=1)
-        found = any_valid.any()
-        i = jnp.argmax(any_valid)
-        masked_util = jnp.where(valid[i], util, jnp.inf)
-        d = jnp.argmin(masked_util)
-        return i, d, found
+    any_valid = valid.any(axis=1)
+    found = any_valid.any()
+    i = jnp.argmax(any_valid)
+    masked_util = jnp.where(valid[i], util, jnp.inf)
+    d = jnp.argmin(masked_util)
+    return i, d, found
 
 
 # ---------------------------------------------------------------------------
@@ -446,8 +439,6 @@ def _balance_fast(state: ClusterState, cfg: EquilibriumConfig | None = None,
     * ``"jax-legacy"`` — the first-generation per-source jitted kernel
       (one dispatch + one blocking sync per source per move), retained as
       the measured baseline for benchmarks/bench_planner.py.
-
-    When JAX is unavailable every engine falls back to NumPy.
     """
     cfg = cfg or EquilibriumConfig()
     if engine is None:
@@ -456,15 +447,13 @@ def _balance_fast(state: ClusterState, cfg: EquilibriumConfig | None = None,
         raise ValueError(f"unknown engine {engine!r}: "
                          "expected 'numpy', 'batch' or 'jax-legacy'")
     if engine == "batch":
-        if _HAVE_JAX:
-            from .equilibrium_batch import _balance_batch
-            return _balance_batch(state, cfg,
-                                  record_trajectory=record_trajectory,
-                                  record_free_space=record_free_space,
-                                  stats_out=stats_out,
-                                  source_bounds=source_bounds)
-        engine = "numpy"                        # pragma: no cover
-    use_legacy_jax = engine == "jax-legacy" and _HAVE_JAX
+        from .equilibrium_batch import _balance_batch
+        return _balance_batch(state, cfg,
+                              record_trajectory=record_trajectory,
+                              record_free_space=record_free_space,
+                              stats_out=stats_out,
+                              source_bounds=source_bounds)
+    use_legacy_jax = engine == "jax-legacy"
     if source_bounds and use_legacy_jax:
         raise ValueError("source_bounds is not supported by the jax-legacy "
                          "engine: its kernel does not expose the candidate "
@@ -619,7 +608,7 @@ def _pick_jax(dense: DenseState, rows: np.ndarray, src_idx: int,
     # math in float64 — without x64, jnp.asarray silently downcasts every
     # float64 input to float32 and near-threshold count/variance tests can
     # flip (caught by the lifecycle fuzzer under non-default count_slack)
-    with enable_x64():
+    with jax.enable_x64(True):
         i, d, found = _jax_select(
             jnp.asarray(sizes), jnp.asarray(cls), jnp.asarray(member),
             jnp.asarray(peer), jnp.asarray(own_dom_eq),

@@ -191,17 +191,13 @@ def planners_in_class(equivalence: str) -> tuple[str, ...]:
     """Registered planner names tagged with ``equivalence``, sorted.
 
     Lazy planner modules are imported first so their registrations are
-    visible; one whose import fails (missing optional dependency) is
-    skipped rather than raised — differential consumers enumerate what
-    can actually run here.
+    visible; an import failure propagates, so a broken engine can never
+    drop out of a differential comparison unnoticed.
     """
     import importlib
     for name, module in _LAZY_PLANNERS.items():
         if name not in _REGISTRY:
-            try:
-                importlib.import_module(module)
-            except Exception:            # pragma: no cover - optional deps
-                pass
+            importlib.import_module(module)
     return tuple(sorted(n for n, spec in _REGISTRY.items()
                         if spec.equivalence == equivalence))
 
@@ -359,8 +355,7 @@ class BatchEquilibriumPlanner:
     carry warm across :meth:`plan` calls; passing a different state
     object rebinds (and cold-starts) transparently.  ``warm=False``
     forces a cold start on every call — the reference behaviour the
-    delta-absorption tests compare against.  Without JAX the dense-NumPy
-    engine is used instead (bit-identical sequences).
+    delta-absorption tests compare against.
     """
 
     name = "equilibrium_batch"
@@ -382,14 +377,9 @@ class BatchEquilibriumPlanner:
                                    source_bounds=source_bounds,
                                    pipeline=pipeline)
         self._impl = None                # BatchPlanner, bound lazily
-        self._fallback = None            # numpy planner when JAX is absent
 
     def _bind(self, state: ClusterState):
-        from .equilibrium_batch import _HAVE_JAX, BatchPlanner
-        if not _HAVE_JAX:                # pragma: no cover - numpy fallback
-            if self._fallback is None:
-                self._fallback = EquilibriumPlanner(self.cfg)
-            return None
+        from .equilibrium_batch import BatchPlanner
         if self._impl is None or self._impl.state is not state:
             self._impl = BatchPlanner(state, self.cfg, **self._engine_kwargs)
         return self._impl
@@ -397,10 +387,6 @@ class BatchEquilibriumPlanner:
     def plan(self, state, *, budget=None, record_trajectory=False,
              record_free_space=True):
         impl = self._bind(state)
-        if impl is None:                 # pragma: no cover - numpy fallback
-            return self._fallback.plan(
-                state, budget=budget, record_trajectory=record_trajectory,
-                record_free_space=record_free_space)
         if not self.warm:
             impl.reset()
         with _plan_span(self.name) as sp:

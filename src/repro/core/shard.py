@@ -10,7 +10,7 @@ clusters past one accelerator's memory.  This module splits that axis
 into contiguous ascending blocks, one per mesh shard, and runs the
 *same* chunk step (:func:`_shard_chunk_impl` mirrors
 ``equilibrium_batch._plan_chunk_impl`` expression for expression) under
-:func:`jax.experimental.shard_map.shard_map`:
+:func:`jax.shard_map`:
 
 * **sharded**: the device axis (``PartitionSpec("dev")``) of the row
   tables ``rows_on``/``nrows``, the ``dst_ok`` / ``pool_counts`` /
@@ -24,7 +24,7 @@ into contiguous ascending blocks, one per mesh shard, and runs the
   (``used``/``util``/``order`` and the device registry constants) and
   the scalar moments — each shard updates them with bitwise-identical
   expressions from replicated inputs, so they stay replicated without
-  ``check_rep`` (which ``shard_map`` cannot verify through the
+  ``check_vma`` (which ``shard_map`` cannot verify through the
   collectives here anyway).
 
 Cross-shard communication happens in exactly three places, and the
@@ -72,8 +72,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import legality
@@ -96,7 +94,7 @@ def _shard_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, *,
     reductions spliced in.  Everything that is not a destination-axis
     slice or an owner scatter is computed redundantly (and identically)
     on every shard — that redundancy is what keeps the replicated carry
-    elements replicated under ``check_rep=False``.
+    elements replicated under ``check_vma=False``.
 
     ``dyn``/``const`` have the ``_plan_chunk_impl`` layout; the sharded
     elements arrive as this shard's local block — the device axis of
@@ -525,32 +523,38 @@ _CONST_SPECS = (_R, _R, _R, _R) + (P("dev"),) * 8 + (P(None, "dev"),)
 _SHARD_FNS: dict[int, object] = {}
 
 
+def shard_chunk_program(mesh: Mesh):
+    """The jitted sharded chunk dispatch over a 1-D ``("dev",)`` mesh.
+    The carry is donated, mirroring the serial wrapper; one compiled
+    program per tile geometry, like the serial ``_plan_chunk``."""
+    n_shards = mesh.devices.size
+
+    @partial(jax.jit, static_argnames=("k", "kb", "rb", "m", "backend",
+                                       "bounds", "telemetry"),
+             donate_argnums=(0,))
+    def fn(dyn, const, slack, headroom, min_dvar, n_real, *, k, kb, rb,
+           m, backend, bounds, telemetry=False):
+        body = partial(_shard_chunk_impl, k=k, kb=kb, rb=rb, m=m,
+                       backend=backend, bounds=bounds,
+                       telemetry=telemetry, axis="dev",
+                       n_shards=n_shards)
+        return jax.shard_map(
+            body, mesh=mesh,
+            in_specs=(_DYN_SPECS, _CONST_SPECS, _R, _R, _R, _R),
+            out_specs=(_DYN_SPECS, _R, _R, P("dev"), _R, _R),
+            check_vma=False,
+        )(dyn, const, slack, headroom, min_dvar, n_real)
+
+    return fn
+
+
 def _shard_chunk_fn(n_shards: int):
-    """The jitted sharded chunk dispatch for an ``n_shards``-way mesh
-    (cached per mesh size; one compiled program per tile geometry, like
-    the serial ``_plan_chunk``).  The carry is donated, mirroring the
-    serial wrapper."""
+    """:func:`shard_chunk_program` on the first ``n_shards`` visible JAX
+    devices, cached per mesh size."""
     fn = _SHARD_FNS.get(n_shards)
     if fn is None:
         mesh = Mesh(np.asarray(jax.devices()[:n_shards]), ("dev",))
-
-        @partial(jax.jit, static_argnames=("k", "kb", "rb", "m", "backend",
-                                           "bounds", "telemetry"),
-                 donate_argnums=(0,))
-        def fn(dyn, const, slack, headroom, min_dvar, n_real, *, k, kb, rb,
-               m, backend, bounds, telemetry=False):
-            body = partial(_shard_chunk_impl, k=k, kb=kb, rb=rb, m=m,
-                           backend=backend, bounds=bounds,
-                           telemetry=telemetry, axis="dev",
-                           n_shards=n_shards)
-            return shard_map(
-                body, mesh=mesh,
-                in_specs=(_DYN_SPECS, _CONST_SPECS, _R, _R, _R, _R),
-                out_specs=(_DYN_SPECS, _R, _R, P("dev"), _R, _R),
-                check_rep=False,
-            )(dyn, const, slack, headroom, min_dvar, n_real)
-
-        _SHARD_FNS[n_shards] = fn
+        fn = _SHARD_FNS[n_shards] = shard_chunk_program(mesh)
     return fn
 
 
@@ -725,7 +729,7 @@ def chunk_memory_stats(bp: BatchPlanner, telemetry: bool = False) -> dict:
     report).  Syncs the planner (building the carry if needed) so the
     lowering sees the real shapes; returns {} for a degenerate cluster
     with nothing to plan."""
-    with enable_x64():
+    with jax.enable_x64(True):
         bp.sync()
         if bp._dyn is None:
             return {}

@@ -243,16 +243,20 @@ class MovementThrottle:
         moved = 0.0
         still: list[_Transfer] = []
         for t in self.in_flight:
+            # a shard re-targeted back onto the device that still holds
+            # it has nothing left to copy (reading and writing one
+            # device's budget would otherwise stall it forever)
+            local = t.src_holds and t.holder == t.mv.dst_osd
             want = min(t.remaining, budget.get(t.mv.dst_osd, bw))
             if t.src_holds:
                 want = min(want, budget.get(t.holder, bw))
-            if want > 0.0:
+            if want > 0.0 and not local:
                 got = take(t.mv.dst_osd, want)
                 if t.src_holds:
                     got = take(t.holder, got)
                 t.remaining -= got
                 moved += got
-            if t.remaining <= 1e-6:
+            if t.remaining <= 1e-6 or local:
                 self.completed_moves += 1
                 self.completed_bytes += float(t.mv.size)
                 self.completed_progress_bytes += float(t.mv.size) - t.remaining

@@ -45,7 +45,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 __all__ = ["CarryDims", "BucketShape", "FleetPack"]
 
@@ -316,7 +315,8 @@ class _Bucket:
 
     def dispatch_scalars(self):
         """The stacked traced scalars as device arrays (cached; callers
-        must hold ``enable_x64()`` so the float64 dtypes survive)."""
+        must hold ``jax.enable_x64(True)`` so the float64 dtypes
+        survive)."""
         if self.dev_scalars is None:
             self.dev_scalars = tuple(jnp.asarray(a) for a in self.scalars)
         return self.dev_scalars
@@ -438,7 +438,7 @@ class FleetPack:
         axis, whose bucket width ``bp`` adopts as its ``_r_cap``."""
         shape, i = self.where[key]
         bucket = self.buckets[shape]
-        with enable_x64():      # callers outside a plan tick (detach)
+        with jax.enable_x64(True):  # callers outside a plan tick (detach)
             bp._dyn = _crop_lane_fused(bucket.dyn, np.int32(i),
                                        dims=bucket.dims[i])
         bp._r_cap = shape.r_cap

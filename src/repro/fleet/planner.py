@@ -42,7 +42,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from ..core.cluster import ClusterDelta, ClusterState
 from ..core.equilibrium import EquilibriumConfig
@@ -243,7 +242,7 @@ class FleetPlanner:
         results: dict = {}
         t_tick = time.perf_counter()
         deadline = None if slo is None else t_tick + float(slo)
-        with enable_x64(), \
+        with jax.enable_x64(True), \
                 _obs.span("fleet.tick", cat="fleet", counters=True,
                           clusters=len(keys)) as sp:
             # --- sync phase: per-cluster delta absorption / (re)build,

@@ -128,12 +128,14 @@ _WARM_ENGINES = {"equilibrium_batch", "equilibrium_batch_sharded", "fleet"}
 
 
 def run_lane(tl: GeneratedTimeline, engine: str,
-             equivalence_checks: bool = True) -> LaneResult:
-    """Run one timeline with one engine under the in-lane oracles."""
+             equivalence_checks: bool = True, **planner_kwargs) -> LaneResult:
+    """Run one timeline with one engine under the in-lane oracles;
+    ``planner_kwargs`` reach the engine's factory (``n_shards`` for the
+    sharded lane)."""
     from ..core.equilibrium_batch import dense_rebuild_count
 
     state, events, cfg = tl.build(engine)
-    inner = ScenarioEngine._make_planner(cfg)
+    inner = ScenarioEngine._make_planner(cfg, **planner_kwargs)
     # equivalence lanes are replayed under the lane's configured capacity
     # headroom; baselines (mgr/none) don't honor that knob, so replay at 0
     headroom = cfg.equilibrium.headroom if equivalence_checks else 0.0

@@ -38,15 +38,17 @@ def flash_attention(q, k, v, *, causal=True, window=None, cap=None,
 
 
 @partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def masked_select(valid, util, *, block_rows=256, interpret=False):
+def masked_select(valid, key, *, block_rows=256, interpret=False):
     """Masked move-selection reduction (the batched planner's inner kernel).
 
-    valid (M, D) bool/uint8, util (D,) → (any (M,) bool, dst (M,) int32):
-    per candidate row, whether any destination is legal and the
-    emptiest legal destination (min util, ties → lowest device index).
-    Also callable inside an enclosing jit/scan (the planner's hot loop).
+    valid (M, D) bool, key (D,) int32 exact emptiest-first order key
+    (:func:`repro.kernels.select_move.order_key`) → (any (M,) bool,
+    dst (M,) int32): per candidate row, whether any destination is legal
+    and the emptiest legal destination (min key, ties → lowest device
+    index).  Also callable inside an enclosing jit/scan (the planner's
+    hot loop).
     """
-    return masked_select_fwd(valid, util, block_rows=block_rows,
+    return masked_select_fwd(valid, key, block_rows=block_rows,
                              interpret=interpret)
 
 

@@ -9,78 +9,108 @@ planning step and then needs, **per candidate shard row**:
   toward the lowest device index — the faithful planner's stable scan
   order).
 
-That is a masked-argmin row reduction: ``argmin_d where(valid, util, +inf)``.
-This module provides the Pallas formulation — grid over row blocks, one
-``(block_rows, n_dev)`` tile in VMEM per step, the ``util`` vector
-broadcast to every step — matching ``masked_select_ref`` in
-:mod:`repro.kernels.ref` bit-for-bit (property-tested in
-tests/test_kernels.py).
+That is a masked argmin over the (utilization, device index) order.  The
+planner's utilizations are float64, which the TPU's vector units do not
+have, and a float32 cast would merge or reorder near-equal values.  The
+kernel therefore reduces an exact int32 *order key* instead
+(:func:`order_key`): ``key[a] < key[b]`` iff ``util[a] < util[b]``, and
+equal utilizations share a key, so the kernel's lowest-index tie-break
+yields exactly the (util, index) minimum that ``masked_select_ref`` in
+:mod:`repro.kernels.ref` computes from the float64 values.
 
-On TPU the call sites compile to Mosaic (pad ``n_dev`` to a lane multiple
-and use float32 utilization); on this CPU container the kernel runs with
-``interpret=True``.  The planner's default CPU backend is the jnp
-reference (identical semantics, no interpreter overhead); the Pallas path
-is selected with ``select_backend="pallas"`` or automatically on TPU.
+Layout: a grid over row blocks, one ``(block_rows, D)`` int32 tile of the
+validity matrix in VMEM per step, the ``(1, D)`` key row broadcast to
+every step; every block is 2-D and every value 32-bit, so Mosaic needs
+no relayout.  On TPU the call compiles to Mosaic; off-TPU it runs with
+``interpret=True`` (tests only — the planner's CPU backend is the jnp
+reference).
 """
 
 from __future__ import annotations
 
-import functools
+import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
+#: masked-out key: above every real key (keys are < the device count)
+_NO_DST = int(np.iinfo(np.int32).max)
+#: int32 block index (under x64 a bare 0 would make the index map int64)
+_I0 = np.int32(0)
 
-def _select_kernel(valid_ref, util_ref, any_ref, dst_ref):
+
+def order_key(util: jax.Array, order: jax.Array) -> jax.Array:
+    """Exact int32 emptiest-first key from a fullest-first order.
+
+    ``order`` (D,) lists device indices sorted by (-util, index) — the
+    batch engine's maintained order (``legality.fullest_first``).
+    Equal-utilization runs of that order share one key; runs further
+    back (emptier) get smaller keys, so ``key[a] < key[b]`` iff
+    ``util[a] < util[b]``.  Only float equality is evaluated, never
+    float arithmetic, so the key is exact on every backend.
+    """
+    n = order.shape[0]
+    u = util[order]
+    starts = jnp.concatenate([jnp.ones((1,), bool), u[1:] != u[:-1]])
+    run = jnp.cumsum(starts.astype(jnp.int32))          # 1 = fullest run
+    return jnp.zeros((n,), jnp.int32).at[order].set(n - run)
+
+
+def _select_kernel(valid_ref, key_ref, any_ref, dst_ref):
     """One grid step: a (block_rows, D) tile of the validity matrix."""
-    valid = valid_ref[...] != 0                       # (bm, D) bool
-    util = util_ref[...]                              # (D,)
-    masked = jnp.where(valid, util[None, :], jnp.inf)
-    any_ref[...] = valid.any(axis=1)
-    dst_ref[...] = jnp.argmin(masked, axis=1).astype(jnp.int32)
+    # every constant is an explicit int32: the kernel may be traced with
+    # x64 enabled, where a bare Python int would widen to int64
+    none = jnp.int32(_NO_DST)
+    masked = jnp.where(valid_ref[...] != jnp.int32(0), key_ref[...], none)
+    best = jnp.min(masked, axis=1, keepdims=True)             # (bm, 1)
+    lane = lax.broadcasted_iota(jnp.int32, masked.shape, 1)
+    past = jnp.int32(masked.shape[1])
+    dst_ref[...] = jnp.min(jnp.where(masked == best, lane, past),
+                           axis=1, keepdims=True)
+    any_ref[...] = (best < none).astype(jnp.int32)
 
 
-def masked_select_fwd(valid: jax.Array, util: jax.Array, *,
+def masked_select_fwd(valid: jax.Array, key: jax.Array, *,
                       block_rows: int = 256,
                       interpret: bool = False) -> tuple[jax.Array, jax.Array]:
-    """valid: (M, D) uint8/bool, util: (D,) → (any (M,) bool, dst (M,) int32).
+    """valid: (M, D) bool, key: (D,) int32 → (any (M,) bool, dst (M,) int32).
 
-    Rows are padded to a ``block_rows`` multiple and the device axis to a
-    128-lane multiple (padding is invalid / +inf, so it never wins the
-    argmin and never sets ``any``).
+    Rows are padded to a ``block_rows`` multiple (a multiple of 8) and the
+    device axis to a 128-lane multiple (padding is invalid, so it never
+    wins the argmin and never sets ``any``).  Rows with no legal
+    destination return dst 0, like the reference.
     """
     M, D = valid.shape
-    bm = min(block_rows, max(M, 1))
+    bm = min(-(-block_rows // 8) * 8, -(-max(M, 1) // 8) * 8)
     nm = -(-M // bm)
     pad_m = nm * bm - M
     pad_d = (-D) % 128
-    if valid.dtype != jnp.uint8:
-        valid = valid.astype(jnp.uint8)
-    if pad_m or pad_d:
-        valid = jnp.pad(valid, ((0, pad_m), (0, pad_d)))
-    if pad_d:
-        util = jnp.pad(util, (0, pad_d), constant_values=jnp.inf)
+    valid = jnp.pad(valid.astype(jnp.int32), ((0, pad_m), (0, pad_d)))
+    key = jnp.pad(key.astype(jnp.int32), (0, pad_d),
+                  constant_values=_NO_DST)[None, :]
     Dp = D + pad_d
 
     any_out, dst_out = pl.pallas_call(
         _select_kernel,
         grid=(nm,),
         in_specs=[
-            pl.BlockSpec((bm, Dp), lambda i: (i, 0)),
-            pl.BlockSpec((Dp,), lambda i: (0,)),
+            pl.BlockSpec((bm, Dp), lambda i: (i, _I0)),
+            pl.BlockSpec((1, Dp), lambda i: (_I0, _I0)),
         ],
         out_specs=[
-            pl.BlockSpec((bm,), lambda i: (i,)),
-            pl.BlockSpec((bm,), lambda i: (i,)),
+            pl.BlockSpec((bm, 1), lambda i: (i, _I0)),
+            pl.BlockSpec((bm, 1), lambda i: (i, _I0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nm * bm,), jnp.bool_),
-            jax.ShapeDtypeStruct((nm * bm,), jnp.int32),
+            jax.ShapeDtypeStruct((nm * bm, 1), jnp.int32),
+            jax.ShapeDtypeStruct((nm * bm, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(valid, util)
-    return any_out[:M], dst_out[:M]
+        name="masked_select",
+    )(valid, key)
+    return any_out[:M, 0] != 0, dst_out[:M, 0]
 
 
 def compact_parked(order_k: jax.Array,

@@ -104,7 +104,7 @@ class ScenarioEngine:
         self._expansions = 0
 
     @staticmethod
-    def _make_planner(cfg: SimConfig) -> Planner:
+    def _make_planner(cfg: SimConfig, **extra) -> Planner:
         """Resolve ``cfg.balancer`` through the planner registry.
 
         The planner's own config comes from the SimConfig field its
@@ -116,13 +116,14 @@ class ScenarioEngine:
         covers every known delta type with or without a stash, so warm
         planners stay warm across arbitrary timelines regardless of
         chunk geometry.)  Unaccepted kwargs are dropped by
-        :func:`~repro.core.planner.create_planner`.
+        :func:`~repro.core.planner.create_planner`, and ``extra`` is
+        passed on as given.
         """
         spec = get_planner_spec(cfg.balancer)    # ValueError when unknown
         kwargs = {"chunk": max(1, cfg.moves_per_tick)}
         if spec.sim_config_attr is not None:
             kwargs["cfg"] = getattr(cfg, spec.sim_config_attr)
-        return create_planner(cfg.balancer, **kwargs)
+        return create_planner(cfg.balancer, **kwargs, **extra)
 
     # -- main loop -----------------------------------------------------------
 

@@ -182,3 +182,43 @@ def test_random_clusters_valid(n_hosts, pg_count, size, seed):
 @given(seed=st.integers(0, 2**16))
 def test_placement_deterministic(seed):
     _check_placement_deterministic(seed)
+
+
+def _place_pg_loop(devices, pool, pg_index, seed=0):
+    """The per-device loop the array-based placement replaced: the
+    reference its draws must reproduce exactly."""
+    rng = np.random.default_rng((seed, pool.id, pg_index))
+    taken, acting = set(), []
+    for step in pool.rule.steps:
+        domains = set()
+        pool_devs = [d for d in devices if step.device_class is None
+                     or d.device_class == step.device_class]
+        for _ in range(step.count):
+            cands = [d for d in pool_devs if d.id not in taken
+                     and d.domain(step.failure_domain) not in domains]
+            weights = np.array([d.capacity for d in cands], dtype=np.float64)
+            weights /= weights.sum()
+            pick = cands[int(rng.choice(len(cands), p=weights))]
+            acting.append(pick.id)
+            taken.add(pick.id)
+            domains.add(pick.domain(step.failure_domain))
+    return acting
+
+
+@pytest.mark.parametrize("rule", [
+    PlacementRule.replicated(3, "host"),
+    PlacementRule.replicated(2, "rack"),
+    PlacementRule.erasure(4, 2, "host", "hdd"),
+    PlacementRule.hybrid([RuleStep("ssd", 1, "host"),
+                          RuleStep("hdd", 2, "host")]),
+], ids=["r3-host", "r2-rack", "ec42-hdd", "hybrid"])
+def test_placement_matches_loop_reference(rule):
+    rng = np.random.default_rng(5)
+    devs = [Device(id=i, capacity=float(rng.uniform(2, 12)) * TiB,
+                   device_class="ssd" if i % 5 == 0 else "hdd",
+                   host=f"host{i // 2}", rack=f"rack{i // 6}")
+            for i in range(40)]
+    pool = Pool(3, "p", 24, rule, stored_bytes=TiB)
+    for pg in range(pool.pg_count):
+        assert place_pg(devs, pool, pg, seed=9) == \
+            _place_pg_loop(devs, pool, pg, seed=9)

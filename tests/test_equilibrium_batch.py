@@ -170,6 +170,20 @@ def test_batch_pallas_backend_identical():
     assert as_tuples(a) == as_tuples(b)
 
 
+def test_select_backend_resolution_on_tpu(monkeypatch):
+    """On a TPU "auto" names the compiled kernel and the interpreting
+    backend is refused; unknown names are refused everywhere."""
+    import jax
+    from repro.core.equilibrium_batch import BatchPlanner
+    with pytest.raises(ValueError, match="unknown select_backend"):
+        BatchPlanner(small_test_cluster(), select_backend="mosaic")
+    assert BatchPlanner(small_test_cluster()).select_backend == "ref"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert BatchPlanner(small_test_cluster()).select_backend == "pallas-tpu"
+    with pytest.raises(ValueError, match="without a TPU"):
+        BatchPlanner(small_test_cluster(), select_backend="pallas")
+
+
 def test_batch_empty_and_degenerate_clusters():
     from repro.core import ClusterState, Device, PlacementRule, Pool, TiB
     devs = [Device(id=0, capacity=8 * TiB, device_class="hdd", host="h0")]

@@ -6,8 +6,10 @@ import numpy as np
 import pytest
 
 from repro.kernels.ops import flash_attention, masked_select, ssd_scan
+from repro.core.legality import fullest_first
 from repro.kernels.ref import (flash_attention_ref, masked_select_ref,
                                ssd_scan_ref)
+from repro.kernels.select_move import order_key
 
 TOL = {jnp.float32: dict(rtol=2e-3, atol=2e-3),
        jnp.bfloat16: dict(rtol=2e-2, atol=2e-2)}
@@ -88,6 +90,12 @@ def test_flash_matches_model_attention():
 # masked move-selection reduction (the batched planner's inner kernel)
 
 
+def _key(util):
+    """The planner's kernel input: the exact order key of ``util``."""
+    order = jnp.asarray(fullest_first(np.asarray(util)), jnp.int32)
+    return order_key(jnp.asarray(util), order)
+
+
 @pytest.mark.parametrize("M,D,block_rows", [
     (8, 16, 256),
     (200, 995, 256),      # planner-shaped: k*row_block rows × OSDs
@@ -97,10 +105,15 @@ def test_flash_matches_model_attention():
 def test_masked_select_matches_ref(M, D, block_rows):
     rng = np.random.default_rng(42)
     valid = jnp.asarray(rng.random((M, D)) < 0.05)
-    util = jnp.asarray(rng.random(D).astype(np.float32))
-    any_k, dst_k = masked_select(valid, util, block_rows=block_rows,
-                                 interpret=True)
-    any_r, dst_r = masked_select_ref(valid, util)
+    # float64 utilizations with runs of exact ties (the faithful order's
+    # index tie-break must survive the key encoding)
+    util = np.where(rng.random(D) < 0.3, rng.integers(0, 4, D) / 4.0,
+                    rng.random(D))
+    with jax.enable_x64(True):
+        util = jnp.asarray(util)
+        any_k, dst_k = masked_select(valid, _key(util),
+                                     block_rows=block_rows, interpret=True)
+        any_r, dst_r = masked_select_ref(valid, util)
     np.testing.assert_array_equal(np.asarray(any_k), np.asarray(any_r))
     # dst is defined only where a legal destination exists
     sel = np.asarray(any_r)
@@ -113,7 +126,7 @@ def test_masked_select_tie_break_lowest_index():
     index — the faithful planner's stable emptiest-first scan order."""
     valid = jnp.asarray(np.array([[True, True, True, False]]))
     util = jnp.asarray(np.array([0.5, 0.2, 0.2, 0.0], np.float32))
-    for fn in (lambda v, u: masked_select(v, u, interpret=True),
+    for fn in (lambda v, u: masked_select(v, _key(u), interpret=True),
                masked_select_ref):
         anyv, dst = fn(valid, util)
         assert bool(anyv[0]) and int(dst[0]) == 1
@@ -122,8 +135,24 @@ def test_masked_select_tie_break_lowest_index():
 def test_masked_select_all_invalid_row():
     valid = jnp.asarray(np.zeros((3, 7), bool))
     util = jnp.asarray(np.linspace(0, 1, 7).astype(np.float32))
-    anyv, _ = masked_select(valid, util, interpret=True)
+    anyv, _ = masked_select(valid, _key(util), interpret=True)
     assert not np.asarray(anyv).any()
+
+
+def test_order_key_separates_values_float32_would_merge():
+    """Utilizations one float64 ulp apart round to the same float32; the
+    order key keeps them apart, so the kernel still picks the smaller."""
+    base = 0.7
+    util = np.array([np.nextafter(base, 1.0), base, base, 0.9])
+    assert len(set(util.astype(np.float32).tolist())) == 2
+    valid = jnp.asarray(np.array([[True, True, False, True],
+                                  [True, False, True, True]]))
+    with jax.enable_x64(True):
+        key = _key(jnp.asarray(util))
+        anyv, dst = masked_select(valid, key, interpret=True)
+        _, dst_r = masked_select_ref(valid, jnp.asarray(util))
+    assert np.asarray(key).tolist() == [2, 1, 1, 3]
+    assert np.asarray(dst).tolist() == np.asarray(dst_r).tolist() == [1, 2]
 
 
 # ---------------------------------------------------------------------------

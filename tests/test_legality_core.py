@@ -172,7 +172,6 @@ def test_variance_improves_numpy_jax_bit_identical():
     — the foundation of the engines' by-construction bit-identity."""
     jax = pytest.importorskip("jax")
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     rng = np.random.default_rng(1)
     n = 32
@@ -182,7 +181,7 @@ def test_variance_improves_numpy_jax_bit_identical():
     us, usq = float(util.sum()), float((util ** 2).sum())
     size = rng.uniform(0.01, 0.4, (8, 1)) * TiB
     src = 5
-    with enable_x64():
+    with jax.enable_x64(True):
         np_ok = legality.variance_improves(
             used[src], used[None, :], cap[src], cap[None, :], util[src],
             util[None, :], size, us, usq, float(n), 0.0)

@@ -464,6 +464,33 @@ def test_scenario_warm_batch_identical_to_cold(name):
         json.dumps(cold["metrics"], sort_keys=True)
 
 
+def test_planners_in_class_raises_when_an_engine_cannot_import(monkeypatch):
+    """A registered engine whose module fails to import must fail the
+    enumeration, not silently drop out of the equivalence class."""
+    from repro.core import planner as planner_mod
+    monkeypatch.setitem(planner_mod._LAZY_PLANNERS, "broken_engine",
+                        "repro.core.no_such_engine_module")
+    with pytest.raises(ImportError):
+        planner_mod.planners_in_class("equilibrium")
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The entry points' cache helper defers to JAX_COMPILATION_CACHE_DIR
+    and otherwise keeps the cache at the repo's fixed .cache/jax."""
+    import jax
+    from repro import compile_cache
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: pytest.fail(f"config changed: {a}"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jax")
+    assert compile_cache.enable_compile_cache() == "/elsewhere/jax"
+    seen = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: seen.append(a))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.enable_compile_cache()
+    assert path.endswith("/.cache/jax")
+    assert seen == [("jax_compilation_cache_dir", path)]
+
+
 def test_engine_accepts_injected_planner():
     """Third-party planners plug into the scenario engine by instance."""
 

@@ -171,7 +171,10 @@ def _check_throttle_conservation(seed, n_ops):
 
 # deterministic spine (hypothesis is optional in the container image)
 @pytest.mark.parametrize("seed,n_ops", [(0, 10), (1, 25), (2, 40), (3, 60),
-                                        (7, 80), (13, 120)])
+                                        (7, 80), (13, 120),
+                                        # a shard re-targeted back onto
+                                        # its holder (once a stalled drain)
+                                        (13, 10), (2, 120)])
 def test_throttle_conservation_cases(seed, n_ops):
     _check_throttle_conservation(seed, n_ops)
 

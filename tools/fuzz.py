@@ -17,9 +17,11 @@ Modes:
   real regression of the same shape.
 
 ``--shard-subprocess N`` additionally runs every Nth seed's timeline
-through the sharded engine on a forced multi-device host mesh in a
-subprocess (``tools/shard_check.py --timeline``) and compares its move
-stream and metrics hashes against the in-process reference lane.
+through the sharded engine on a ``--shard-devices``-wide mesh and
+compares its move stream and metrics hashes against the reference lane.
+On a CPU host the mesh is forced in a subprocess
+(``tools/shard_check.py --timeline``); on a TPU host the lane runs in
+this process on the first ``--shard-devices`` chips.
 
 Examples::
 
@@ -69,9 +71,34 @@ def resolve_engines(spec: str):
     return tuple(spec.split(","))
 
 
-def shard_subprocess_check(tl, ref_lane, devices: int) -> None:
-    """Run the timeline's sharded lane on a forced N-device host mesh in
-    a subprocess; raise OracleFailure("agreement") on hash mismatch."""
+def shard_lane_check(tl, ref_lane, devices: int) -> None:
+    """Run the timeline's sharded lane on a ``devices``-wide mesh; raise
+    OracleFailure("agreement") on hash mismatch.
+
+    On a TPU host the lane runs in this process over
+    ``jax.devices()[:devices]`` (a chip belongs to one process, and this
+    one already holds it).  Elsewhere a subprocess forces ``devices``
+    host devices, since JAX fixes the CPU device count at start-up."""
+    import jax
+    from repro.fuzz import OracleFailure
+    if jax.default_backend() == "tpu":
+        from repro.fuzz.harness import run_lane
+        got = lane_hashes(run_lane(tl, "equilibrium_batch_sharded",
+                                   n_shards=devices))
+    else:
+        got = _shard_subprocess(tl, devices)
+    want = lane_hashes(ref_lane)
+    for key in ("moves_sha", "metrics_sha"):
+        if got[key] != want[key]:
+            raise OracleFailure(
+                "agreement",
+                f"sharded lane (mesh={devices}) {key} "
+                f"mismatch: {got[key]} != {want[key]}")
+
+
+def _shard_subprocess(tl, devices: int) -> dict:
+    """The sharded lane's hashes from ``tools/shard_check.py --timeline``
+    on a forced ``devices``-device host mesh."""
     from repro.fuzz import OracleFailure
     script = os.path.join(os.path.dirname(__file__), "shard_check.py")
     env = dict(os.environ)
@@ -86,21 +113,14 @@ def shard_subprocess_check(tl, ref_lane, devices: int) -> None:
             [sys.executable, script, "--timeline", path,
              "--devices", str(devices)],
             env=env, capture_output=True, text=True, timeout=1200)
-        if proc.returncode != 0:
-            raise OracleFailure(
-                "agreement",
-                f"sharded subprocess lane (mesh={devices}) failed rc="
-                f"{proc.returncode}:\n{proc.stderr[-2000:]}")
-        got = json.loads(proc.stdout.strip().splitlines()[-1])
-        want = lane_hashes(ref_lane)
-        for key in ("moves_sha", "metrics_sha"):
-            if got[key] != want[key]:
-                raise OracleFailure(
-                    "agreement",
-                    f"sharded subprocess lane (mesh={devices}) {key} "
-                    f"mismatch: {got[key]} != {want[key]}")
     finally:
         os.unlink(path)
+    if proc.returncode != 0:
+        raise OracleFailure(
+            "agreement",
+            f"sharded subprocess lane (mesh={devices}) failed rc="
+            f"{proc.returncode}:\n{proc.stderr[-2000:]}")
+    return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
 def run_one(tl, engines, baselines=True):
@@ -174,9 +194,9 @@ def main() -> int:
                     help="shrinker predicate-evaluation budget")
     ap.add_argument("--shard-subprocess", type=int, default=0, metavar="N",
                     help="every Nth seed, also check the sharded-mesh "
-                         "subprocess lane (0 = off)")
+                         "lane (0 = off)")
     ap.add_argument("--shard-devices", type=int, default=2,
-                    help="forced host mesh size for the subprocess lane")
+                    help="mesh size for the sharded-mesh lane")
     args = ap.parse_args()
 
     from repro.fuzz import OracleFailure, load_timeline, mutated
@@ -240,7 +260,7 @@ def main() -> int:
         try:
             ref = run_one(tl, engines, baselines=not args.no_baselines)
             if args.shard_subprocess and i % args.shard_subprocess == 0:
-                shard_subprocess_check(tl, ref, args.shard_devices)
+                shard_lane_check(tl, ref, args.shard_devices)
         except OracleFailure as exc:
             failures += 1
             print(f"seed {seed}: {exc}")

@@ -2,13 +2,14 @@
 """Profile one sharded-planner mesh point: per-device memory of the
 compiled chunk program plus a short timed plan on ``cluster_b(scale)``.
 
-JAX fixes the host device count at process start, so
+JAX fixes the host device count at process start, so on a CPU host
 ``benchmarks/bench_planner.py`` spawns this script once per mesh size
 with ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` and stitches
-the JSON lines into its ``planner.shard.*`` rows.  The cluster build is
-pickle-cached and shared across mesh sizes (at scale 8 — the ~8k-OSD,
-~70k-PG profile cluster — building it dominates everything else this
-script does).
+the JSON lines into its ``planner.shard.*`` rows; on a TPU host it calls
+:func:`profile_point` in its own process for each mesh size instead.
+The cluster build is pickle-cached and shared across mesh sizes (at
+scale 8 — the ~8k-OSD, ~70k-PG profile cluster — building it dominates
+everything else this script does).
 
 The memory figures come from XLA's ``memory_analysis`` of the lowered
 chunk executable (:func:`repro.core.shard.chunk_memory_stats`); for an
@@ -83,54 +84,67 @@ def main() -> int:
               f"{args.devices}", file=sys.stderr)
         return 2
 
+    state, build_s, cache_hit = load_state(args.scale, args.cache)
+    out = profile_point(state, n_dev, args.budget,
+                        serial_check=args.serial_check,
+                        trace_out=args.trace_out)
+    out.update(scale=args.scale, build_s=round(build_s, 1),
+               cache_hit=cache_hit)
+    print(json.dumps(out))
+    if out.get("identical") is False:
+        print("sharded/serial move streams diverge", file=sys.stderr)
+        return 1
+    return 0
+
+
+def profile_point(state, n_shards: int, budget: int, *,
+                  serial_check: bool = True,
+                  trace_out: str | None = None) -> dict:
+    """One mesh point on the first ``n_shards`` visible devices: the
+    compiled chunk program's per-device memory, then (``budget`` > 0) a
+    timed ``budget``-move plan and, with ``serial_check``, the serial
+    engine's replan of the same budget (``identical`` in the result)."""
     from repro import obs
     from repro.core import EquilibriumConfig
     from repro.core.equilibrium_batch import DONATED_CARRY
     from repro.core.planner import create_planner
     from repro.core.shard import ShardedBatchPlanner, chunk_memory_stats
 
-    state, build_s, cache_hit = load_state(args.scale, args.cache)
-    out = {"devices": n_dev, "scale": args.scale, "osds": state.n_devices,
-           "pgs": len(state.acting), "build_s": round(build_s, 1),
-           "cache_hit": cache_hit, "donated_carry": DONATED_CARRY}
+    out = {"devices": n_shards, "osds": state.n_devices,
+           "pgs": len(state.acting), "donated_carry": DONATED_CARRY}
 
     # per-participant memory of the compiled chunk program
     mem = chunk_memory_stats(ShardedBatchPlanner(state.copy(),
-                                                 EquilibriumConfig()))
+                                                 EquilibriumConfig(),
+                                                 n_shards=n_shards))
     out.update(mem)
     out["peak_bytes_per_device"] = mem.get("peak_bytes", 0)
 
-    if args.budget:
-        if args.trace_out:
-            obs.start_tracing(args.trace_out)
+    if budget:
+        if trace_out:
+            obs.start_tracing(trace_out)
         # warm call compiles the mesh program; the timed planner is then
         # cold-started (dense build included), as in bench_planner
-        create_planner("equilibrium_batch_sharded").plan(
-            state.copy(), budget=min(args.budget, 16))
-        planner = create_planner("equilibrium_batch_sharded")
+        create_planner("equilibrium_batch_sharded", n_shards=n_shards).plan(
+            state.copy(), budget=min(budget, 16))
+        planner = create_planner("equilibrium_batch_sharded",
+                                 n_shards=n_shards)
         timed = state.copy()
         t0 = time.perf_counter()
-        res = planner.plan(timed, budget=args.budget)
+        res = planner.plan(timed, budget=budget)
         dt = time.perf_counter() - t0
         out.update(moves=len(res.moves), plan_s=round(dt, 3),
                    moves_per_s=round(len(res.moves) / max(dt, 1e-9), 1),
                    shards=res.stats["shards"],
                    pipeline=res.stats["pipeline"])
-        if args.trace_out:
+        if trace_out:
             obs.stop_tracing()
-        if args.serial_check:
+        if serial_check:
             serial = create_planner("equilibrium_batch",
                                     select_backend="ref")
-            ref = serial.plan(state.copy(), budget=args.budget)
+            ref = serial.plan(state.copy(), budget=budget)
             out["identical"] = as_tuples(res.moves) == as_tuples(ref.moves)
-            if not out["identical"]:
-                print(json.dumps(out))
-                print("sharded/serial move streams diverge",
-                      file=sys.stderr)
-                return 1
-
-    print(json.dumps(out))
-    return 0
+    return out
 
 
 if __name__ == "__main__":
