@@ -1,0 +1,9 @@
+"""Median wall time of the window's balancer rounds, in ms (host clock)."""
+
+import numpy as np
+
+
+def read(ctx):
+    if not ctx.requests:
+        return None
+    return float(np.percentile([r.seconds for r in ctx.requests], 50)) * 1e3
