@@ -286,11 +286,11 @@ def main() -> None:
     n_loadgen = 2 if args.quick else 4
     budget = 64
 
-    # tracer first: the telemetry flag is jit-static, so installing it
-    # after warmup would recompile inside the timed window
+    # tracer first: its device counters are a jit-static flag, so
+    # installing it after warmup would recompile inside the timed window
     started = not obs.enabled()
     if started:
-        obs.start_tracing(args.trace_out)
+        obs.start_tracing(args.trace_out, device_counters=True)
     rows = []
     rows += bench_stream(n, budget)
     rows += bench_loadgen(n_loadgen)

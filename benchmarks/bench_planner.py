@@ -418,7 +418,7 @@ def main() -> None:
     # carries the per-call counters the derived columns summarize
     started = not obs.enabled()
     if started:
-        obs.start_tracing(args.trace_out)
+        obs.start_tracing(args.trace_out, device_counters=True)
     rows = []
     for scale in scales:
         t0 = time.perf_counter()

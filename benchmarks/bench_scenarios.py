@@ -139,7 +139,7 @@ def main() -> None:
                      f"{available_planners()}")
     started = not obs.enabled()
     if started:
-        obs.start_tracing(args.trace_out)
+        obs.start_tracing(args.trace_out, device_counters=True)
     bench_scenarios(args.scenario, balancers, seed=args.seed,
                     quick=args.quick, out=args.out)
     if started:

@@ -109,7 +109,7 @@ def main() -> None:
     tracer = None
     if args.trace_out:
         from repro import obs
-        tracer = obs.start_tracing(args.trace_out)
+        tracer = obs.start_tracing(args.trace_out, device_counters=True)
 
     sha = git_sha()
     json_rows = []

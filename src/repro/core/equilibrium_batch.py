@@ -96,11 +96,14 @@ def dense_rebuild_count() -> int:
     return int(_obs_registry().get("batch.rebuilds"))
 
 
-def _fetch(tree):
+def _fetch(tree, what: str):
     """The only device→host transfer point in this module: one call per
-    planning chunk (plus one per re-pad), never per move or per source."""
+    planning chunk (plus one per re-pad), never per move or per source.
+    ``what`` names the reason on the ``batch.fetch`` span: ``chunk``,
+    ``repad``, ``pruned`` or ``absorb``."""
     _obs_registry().inc("batch.host_syncs")
-    return jax.device_get(tree)
+    with _obs.span("batch.fetch", cat="batch", what=what):
+        return jax.device_get(tree)
 
 
 def _select_rows(valid2d, util, backend: str, key=None):
@@ -816,9 +819,12 @@ class BatchPlanner:
     def _build(self) -> None:
         """Full rebuild of the device mirror from ``self.state``."""
         _obs_registry().inc("batch.rebuilds")
-        _obs.point("batch.rebuild", cat="batch",
-                   n_devices=self.state.n_devices,
-                   pending=len(self._pending), invalid=self._invalid)
+        with _obs.span("batch.build", cat="batch",
+                       n_devices=self.state.n_devices,
+                       pending=len(self._pending), invalid=self._invalid):
+            self._build_mirror()
+
+    def _build_mirror(self) -> None:
         from .equilibrium_jax import DenseState
 
         state, cfg = self.state, self.cfg
@@ -1059,6 +1065,15 @@ class BatchPlanner:
         run = self._pending_run()
         if not self._absorbable(run):
             return False
+        with _obs.span("batch.absorb", cat="batch",
+                       deltas=len(run)) as sp:
+            structural, kept_bounds = self._absorb_run(run)
+            sp.set(structural=structural, kept_bounds=kept_bounds)
+        return True
+
+    def _absorb_run(self, run: list[ClusterDelta]) -> tuple[bool, bool]:
+        """The body of :meth:`_absorb`; returns whether the run was
+        structural and whether the source bounds were kept."""
         state, cfg, dense = self.state, self.cfg, self._dense
         added = [d.device for d in run if isinstance(d, DeviceAddDelta)]
         created = [d.pool_id for d in run if isinstance(d, PoolCreateDelta)]
@@ -1082,7 +1097,7 @@ class BatchPlanner:
             used_old, util_old, dst_ok_old, pruned_old = (
                 np.asarray(a) for a in _fetch(
                     (self._dyn[0], self._dyn[1], self._dyn[6],
-                     self._dyn[13])))
+                     self._dyn[13]), "absorb"))
         self._stash = []
 
         # structural extensions first (append-only, per _absorbable)
@@ -1169,7 +1184,8 @@ class BatchPlanner:
             # growth — the cheap per-tick path
             acting = self._dyn[4]
             rows_np, nrows_np = (np.array(a) for a in
-                                 _fetch((self._dyn[7], self._dyn[8])))
+                                 _fetch((self._dyn[7], self._dyn[8]),
+                                        "absorb"))
             if added:
                 pad_rows = np.full((len(added), rows_np.shape[1]), -1,
                                    np.int32)
@@ -1248,12 +1264,9 @@ class BatchPlanner:
         reg.inc("absorb.runs")
         for d in run:
             reg.inc("absorb.deltas", type=type(d).__name__)
-        _obs.point("batch.absorb", cat="batch", deltas=len(run),
-                   structural=bool(structural),
-                   kept_bounds=bool(keep_bounds))
         self._epoch = state.mutation_epoch
         self._drop_synced_pending()
-        return True
+        return bool(structural), bool(keep_bounds)
 
     # -- planning ------------------------------------------------------------
 
@@ -1285,7 +1298,7 @@ class BatchPlanner:
         if pruned is not None:
             acc["pruned"] = int(pruned)
         elif self.source_bounds and self._dyn is not None:
-            acc["pruned"] = int(_fetch(jnp.sum(self._dyn[13])))
+            acc["pruned"] = int(_fetch(jnp.sum(self._dyn[13]), "pruned"))
         tail_flush(acc)
         stats_out["legality_cache"] = self.legality_cache
         stats_out["source_bounds"] = self.source_bounds
@@ -1302,23 +1315,25 @@ class BatchPlanner:
         dense, state = self._dense, self.state
         movements: list[Movement] = []
         records: list[MoveRecord] = []
-        for row, src, dst, tried, _skipped, secs in raw_moves:
-            pg, slot = dense.shard_key[row]
-            mv = Movement(pg, slot, state.devices[src].id,
-                          state.devices[dst].id,
-                          float(dense.sh_size[row]))
-            state.apply(mv)              # re-validates source assignment
-            movements.append(mv)
-            if record_trajectory:
-                records.append(MoveRecord(
-                    movement=mv,
-                    variance_after=state.utilization_variance(),
-                    free_space_after=(state.total_pool_free_space()
-                                      if record_free_space
-                                      else float("nan")),
-                    planning_seconds=secs,
-                    sources_tried=tried,
-                ))
+        with _obs.span("batch.reconcile", cat="batch",
+                       moves=len(raw_moves)):
+            for row, src, dst, tried, _skipped, secs in raw_moves:
+                pg, slot = dense.shard_key[row]
+                mv = Movement(pg, slot, state.devices[src].id,
+                              state.devices[dst].id,
+                              float(dense.sh_size[row]))
+                state.apply(mv)          # re-validates source assignment
+                movements.append(mv)
+                if record_trajectory:
+                    records.append(MoveRecord(
+                        movement=mv,
+                        variance_after=state.utilization_variance(),
+                        free_space_after=(state.total_pool_free_space()
+                                          if record_free_space
+                                          else float("nan")),
+                        planning_seconds=secs,
+                        sources_tried=tried,
+                    ))
         self._epoch = state.mutation_epoch
         self._drop_synced_pending()     # our own replayed movements
         # fully synced to the state: any backlog concern (e.g. our own
@@ -1342,6 +1357,11 @@ class BatchPlanner:
         stats_out["cache_hits"] = int(d.get("batch.cache_hits", 0))
         stats_out["cache_misses"] = int(d.get("batch.cache_misses", 0))
         stats_out["absorbed_deltas"] = self._absorbed_deltas
+
+    def _dispatch(self, telemetry: bool):
+        """:meth:`_dispatch_chunk` under a ``batch.dispatch`` span."""
+        with _obs.span("batch.dispatch", cat="batch"):
+            return self._dispatch_chunk(telemetry)
 
     def _dispatch_chunk(self, telemetry: bool):
         """Async-dispatch one chunk against the current carry (donating
@@ -1393,27 +1413,26 @@ class BatchPlanner:
         reg = _obs_registry()
         if take:
             reg.inc("batch.stash_replayed", take)
-        # static jit flag: the telemetry carry compiles in only while a
-        # tracer is installed (toggling it costs one recompile, counted
-        # like any other); the disabled variant is the exact pre-obs
-        # computation, keeping plan bit-identity trivially
-        telemetry = _obs.enabled()
-        pending = None      # (handles, recompiles, dispatch_s) of chunk i+1
+        # static jit flag: the telemetry carry compiles in only under a
+        # tracer started with device_counters=True (toggling it costs one
+        # recompile, counted like any other); the default variant is the
+        # exact pre-obs computation, traced or not, keeping plan
+        # bit-identity trivially
+        telemetry = _obs.device_counters()
+        pending = None      # (handles, recompiles) of chunk i+1
         while len(raw) < budget and not self._done:
             with _obs.span("batch.chunk", cat="batch") as sp:
                 t0 = time.perf_counter()
                 if pending is None:
-                    handles, recompiles = self._dispatch_chunk(telemetry)
-                    dispatch_s = time.perf_counter() - t0
+                    handles, recompiles = self._dispatch(telemetry)
                     overlapped = False
                 else:
-                    handles, recompiles, dispatch_s = pending
+                    handles, recompiles = pending
                     pending = None
                     overlapped = True
-                t1 = time.perf_counter()
-                moves_np, done, overflow, tel_np, nmax = _fetch(handles)
+                moves_np, done, overflow, tel_np, nmax = _fetch(handles,
+                                                                "chunk")
                 dt = time.perf_counter() - t0
-                sync_s = time.perf_counter() - t1
                 done, overflow, nmax = bool(done), bool(overflow), int(nmax)
                 emitted = moves_np[moves_np[:, 0] >= 0]
                 if (self.pipeline and not done and not overflow
@@ -1423,9 +1442,7 @@ class BatchPlanner:
                     # the next loop iteration will run a full chunk: issue
                     # its dispatch now and let the device overlap it with
                     # the host-side processing of this one
-                    td = time.perf_counter()
-                    pending = (*self._dispatch_chunk(telemetry),
-                               time.perf_counter() - td)
+                    pending = self._dispatch(telemetry)
                     reg.inc("batch.chunks_overlapped")
                 if telemetry:
                     self._record_chunk_tel(reg, tel_np)
@@ -1436,9 +1453,7 @@ class BatchPlanner:
                     # needs no device counter)
                     reg.inc("batch.cache_repairs", len(emitted))
                 sp.set(emitted=len(emitted), done=done, overflow=overflow,
-                       recompiles=recompiles, overlapped=overlapped,
-                       dispatch_s=round(dispatch_s, 6),
-                       sync_s=round(sync_s, 6))
+                       recompiles=recompiles, overlapped=overlapped)
             if len(emitted) == 0 and done and not overflow:
                 self._terminal_seconds += dt    # the fruitless final scan
                                                 # (not an overflow re-pad)
@@ -1475,7 +1490,8 @@ class BatchPlanner:
                 reg.inc("batch.repads")
                 _obs.point("batch.repad", cat="batch",
                            r_cap=self._r_cap)
-                rows_np, nrows_np = _fetch((self._dyn[7], self._dyn[8]))
+                rows_np, nrows_np = _fetch((self._dyn[7], self._dyn[8]),
+                                           "repad")
                 n_carry = rows_np.shape[0]
                 self._r_cap = self._round_cap(int(nrows_np.max())
                                               + self.chunk)

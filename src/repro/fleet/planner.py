@@ -302,7 +302,7 @@ class FleetPlanner:
             live = {key for key in packed
                     if len(raw[key]) < budget_of[key]
                     and not self._clusters[key]._done}
-            telemetry = _obs.enabled()
+            telemetry = _obs.device_counters()
             expired = False
             first_dispatch = True
             rounds = 0
@@ -365,7 +365,7 @@ class FleetPlanner:
                 # phase 2 — one blocking transfer for the whole round
                 # (CI-gated: fleet.round_syncs stays equal to fleet.rounds
                 # no matter how many bucket shapes are in play)
-                fetched = _fetch([p[3] for p in pending])
+                fetched = _fetch([p[3] for p in pending], "chunk")
                 reg.inc("fleet.round_syncs")
                 for (shape, bucket, active, _handles, t0), \
                         (moves_np, done_np, ovf_np, tel_np, nmax_np) \
@@ -442,7 +442,8 @@ class FleetPlanner:
                     # tick costs no device sync here at all
                     if any(participations[key] > 0 or key not in self._pruned
                            for key, i in lanes):
-                        sums = _fetch(jnp.sum(bucket.dyn[13], axis=1))
+                        sums = _fetch(jnp.sum(bucket.dyn[13], axis=1),
+                                      "pruned")
                         for key, i in lanes:
                             self._pruned[key] = int(sums[i])
                     for key, i in lanes:

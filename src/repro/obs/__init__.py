@@ -36,14 +36,16 @@ it for Perfetto.
 from .metrics import MetricsRegistry, labelled, registry
 from .schema import (STATS_SCHEMA, finalize_stats, validate_stats,
                      validate_trace)
-from .trace import (Span, Tracer, enabled, point, read_trace, span,
-                    start_tracing, stop_tracing, to_chrome, tracer, tracing)
+from .trace import (Span, Tracer, device_counters, enabled, point,
+                    read_trace, span, start_tracing, stop_tracing, to_chrome,
+                    tracer, tracing)
 
 __all__ = [
     # metrics
     "MetricsRegistry", "registry", "labelled",
     # tracing
-    "Tracer", "Span", "enabled", "tracer", "tracing", "start_tracing",
+    "Tracer", "Span", "enabled", "device_counters", "tracer", "tracing",
+    "start_tracing",
     "stop_tracing", "span", "point", "read_trace", "to_chrome",
     # schema
     "STATS_SCHEMA", "finalize_stats", "validate_stats", "validate_trace",

@@ -8,6 +8,14 @@ call site, so instrumented code costs ~nothing and, because every write
 happens host-side at span close, never perturbs plan bit-identity
 (property-tested in tests/test_obs.py).
 
+While a tracer is installed and JAX is already imported, every span also
+opens a ``jax.profiler.TraceAnnotation`` of its name, so a profiler
+trace taken meanwhile shows the program's spans on the device events'
+clock.  Installing a tracer does not change what is compiled: the
+device-side counters of the batch engine's chunk program compile in
+only for a tracer started with ``device_counters=True``
+(:func:`device_counters`).
+
 Event records (one JSON object per line in the ``.jsonl`` sink):
 
 * ``{"ev": "meta", "version": 1, ...}`` — header (first line);
@@ -16,7 +24,7 @@ Event records (one JSON object per line in the ``.jsonl`` sink):
   monotonic wall clock (``perf_counter``) relative to tracer start,
   ``cpu`` is µs of process CPU time (``process_time``);
 * ``{"ev": "point", "name", "cat", "ts", "args"}`` — an instant event
-  (a dense rebuild, an absorbed delta run, an overshoot stash);
+  (an overshoot stash, a row-table re-pad);
 * ``{"ev": "counters", "ts", "values", "gauges", "histograms"}`` — the
   final registry snapshot, written once by :meth:`Tracer.close` (the
   footer ``tools/tracestat.py`` and the CI counter assertions read).
@@ -30,14 +38,15 @@ tracing.  :func:`read_trace` normalizes both back to record dicts.
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
 
 from .metrics import registry
 
-__all__ = ["Tracer", "Span", "enabled", "tracer", "start_tracing",
-           "stop_tracing", "tracing", "span", "point", "read_trace",
-           "to_chrome"]
+__all__ = ["Tracer", "Span", "enabled", "device_counters", "tracer",
+           "start_tracing", "stop_tracing", "tracing", "span", "point",
+           "read_trace", "to_chrome"]
 
 TRACE_VERSION = 1
 
@@ -50,8 +59,27 @@ def enabled() -> bool:
     return _tracer is not None
 
 
+def device_counters() -> bool:
+    """True iff the installed tracer asked for device-side counters: the
+    static flag that compiles them into the chunk programs."""
+    t = _tracer
+    return t is not None and t.device_counters
+
+
 def tracer() -> "Tracer | None":
     return _tracer
+
+
+def _annotation(name: str):
+    """An open ``jax.profiler.TraceAnnotation`` of ``name``, or None
+    where JAX is not imported (this module never imports it itself)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    import jax.profiler
+    ann = jax.profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
 
 
 class _NoopSpan:
@@ -85,7 +113,8 @@ class Span:
     trace consumers aggregate."""
 
     __slots__ = ("_tracer", "name", "cat", "args", "_counters", "_snap",
-                 "_t0", "_c0", "wall_s", "cpu_s", "id", "parent", "_tid")
+                 "_t0", "_c0", "wall_s", "cpu_s", "id", "parent", "_tid",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  counters: bool, args: dict):
@@ -110,6 +139,7 @@ class Span:
         stack.append(self.id)
         if self._counters:
             self._snap = registry().snapshot()
+        self._ann = _annotation(self.name)
         self._c0 = time.process_time()
         self._t0 = time.perf_counter()
         return self
@@ -119,6 +149,8 @@ class Span:
         c1 = time.process_time()
         self.wall_s = t1 - self._t0
         self.cpu_s = c1 - self._c0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         t = self._tracer
         stack = t._stack()
         if stack and stack[-1] == self.id:
@@ -139,10 +171,15 @@ class Span:
 
 class Tracer:
     """Collects records and writes them to ``path`` on :meth:`close`
-    (or keeps them in memory when ``path`` is None — the test sink)."""
+    (or keeps them in memory when ``path`` is None — the test sink).
+    ``device_counters`` compiles the chunk programs' device-side
+    counters in while this tracer is installed (a different executable
+    from the untraced one, so off by default)."""
 
-    def __init__(self, path: str | None = None):
+    def __init__(self, path: str | None = None,
+                 device_counters: bool = False):
         self.path = path
+        self.device_counters = device_counters
         self.records: list[dict] = [
             {"ev": "meta", "version": TRACE_VERSION,
              "clock": "perf_counter_us"}]
@@ -223,14 +260,15 @@ def point(name: str, /, cat: str = "", **args) -> None:
         t.point(name, cat, **args)
 
 
-def start_tracing(path: str | None = None) -> Tracer:
+def start_tracing(path: str | None = None,
+                  device_counters: bool = False) -> Tracer:
     """Install a process-global tracer writing to ``path`` on stop
     (in-memory when None).  Raises if one is already installed."""
     global _tracer
     with _lock:
         if _tracer is not None:
             raise RuntimeError("tracing already started")
-        t = Tracer(path)
+        t = Tracer(path, device_counters)
     _tracer = t         # publish only after construction
     return t
 
@@ -248,12 +286,14 @@ def stop_tracing() -> list[dict]:
 class tracing:
     """``with tracing("run.jsonl") as t:`` — scoped start/stop."""
 
-    def __init__(self, path: str | None = None):
+    def __init__(self, path: str | None = None,
+                 device_counters: bool = False):
         self.path = path
+        self.device_counters = device_counters
         self.records: list[dict] = []
 
     def __enter__(self) -> Tracer:
-        self.tracer = start_tracing(self.path)
+        self.tracer = start_tracing(self.path, self.device_counters)
         return self.tracer
 
     def __exit__(self, *exc) -> bool:

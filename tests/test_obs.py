@@ -207,3 +207,105 @@ def test_start_tracing_twice_raises():
     finally:
         assert obs.stop_tracing() is t.records or True
     assert not obs.enabled()
+
+
+# ---------------------------------------------------------------------------
+# the profiler's clock and the compiled chunk program
+
+
+def test_span_opens_a_profiler_annotation_only_while_tracing(monkeypatch):
+    import jax.profiler
+    opened = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            opened.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    off = obs.span("batch.build")
+    with off:
+        pass
+    assert off is obs.span("other")     # still the shared no-op
+    assert opened == []
+    with obs.tracing():
+        with obs.span("planner.plan"):
+            with obs.span("batch.build", cat="batch", n=1):
+                pass
+    assert opened == [("enter", "planner.plan"), ("enter", "batch.build"),
+                      ("exit", "batch.build"), ("exit", "planner.plan")]
+
+
+def test_default_tracing_runs_the_untraced_chunk_program():
+    from repro.core.equilibrium_batch import _plan_chunk
+    state = small_test_cluster()
+    off = create_planner("equilibrium_batch").plan(state.copy())
+    compiled = _plan_chunk._cache_size()
+    reg = registry()
+    snap = reg.snapshot()
+    with obs.tracing():
+        on = create_planner("equilibrium_batch").plan(state.copy())
+    deltas = reg.deltas_since(snap)
+    assert deltas.get("batch.jit_recompiles", 0) == 0
+    assert _plan_chunk._cache_size() == compiled
+    assert "batch.tiles_walked" not in deltas
+    assert tup(on.moves) == tup(off.moves)
+
+
+def test_device_counters_compile_the_tile_counters_in():
+    reg = registry()
+    snap = reg.snapshot()
+    with obs.tracing(device_counters=True):
+        assert obs.device_counters()
+        create_planner("equilibrium_batch").plan(small_test_cluster())
+    assert not obs.device_counters()
+    assert reg.deltas_since(snap).get("batch.tiles_walked", 0) > 0
+
+
+def test_batch_spans_nest_and_every_fetch_names_its_reason():
+    with obs.tracing() as t:
+        create_planner("equilibrium_batch").plan(small_test_cluster())
+    spans = [r for r in t.records if r.get("ev") == "span"]
+    by_id = {r["id"]: r for r in spans}
+    plan = next(r for r in spans if r["name"] == "planner.plan")
+    names = [r["name"] for r in spans]
+    assert names.count("batch.build") == 1
+    assert names.count("batch.reconcile") == 1
+    assert names.count("batch.dispatch") == names.count("batch.chunk") > 0
+    build = next(r for r in spans if r["name"] == "batch.build")
+    assert build["args"]["n_devices"] == small_test_cluster().n_devices
+    reconcile = next(r for r in spans if r["name"] == "batch.reconcile")
+    assert reconcile["args"]["moves"] == plan["args"]["moves"]
+    for r in spans:
+        if r["name"] in ("batch.dispatch", "batch.fetch"):
+            if r["args"].get("what") == "pruned":
+                assert by_id[r["parent"]]["name"] == "planner.plan"
+            else:
+                assert by_id[r["parent"]]["name"] == "batch.chunk"
+    assert "dispatch_s" not in str([r["args"] for r in spans])
+    fetches = [r for r in spans if r["name"] == "batch.fetch"]
+    assert {r["args"]["what"] for r in fetches} <= {"chunk", "repad",
+                                                    "pruned"}
+    assert len(fetches) == plan["args"]["counters"]["batch.host_syncs"]
+    points = {r["name"] for r in t.records if r.get("ev") == "point"}
+    assert "batch.rebuild" not in points
+
+
+def test_absorb_is_a_span_with_its_args():
+    from repro.core import TiB
+    state = small_test_cluster()
+    planner = create_planner("equilibrium_batch")
+    planner.plan(state)
+    state.grow_pool(0, 1.0 * TiB)
+    with obs.tracing() as t:
+        planner.plan(state)
+    absorbs = [r for r in t.records if r.get("name") == "batch.absorb"]
+    assert len(absorbs) == 1 and absorbs[0]["ev"] == "span"
+    assert absorbs[0]["args"]["deltas"] >= 1
+    assert {"structural", "kept_bounds"} <= set(absorbs[0]["args"])
+    assert not any(r.get("name") == "batch.build" for r in t.records)

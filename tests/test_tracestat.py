@@ -2,9 +2,10 @@
 
 The fixture trace under ``tests/fixtures/tracestat/`` is a hand-written,
 schema-valid JSONL trace exercising every derived view: fleet ticks with
-an SLO-expired round, per-cluster plan spans, batch.chunk overlap spans,
-a bench.call with counters, and a counters footer with sharded tile
-counters.  Each CLI view's stdout is compared byte-for-byte against a
+an SLO-expired round, per-cluster plan spans, batch.chunk overlap spans
+(each with its batch.dispatch and batch.fetch children), a bench.call
+with counters, and a counters footer with sharded tile counters.  Each
+CLI view's stdout is compared byte-for-byte against a
 committed golden — any change to the derived-metric math (prune rate,
 tail share, overlap split, freshness buckets) shows up as a readable
 golden diff, not a silent drift.

@@ -122,7 +122,7 @@ def profile_point(state, n_shards: int, budget: int, *,
 
     if budget:
         if trace_out:
-            obs.start_tracing(trace_out)
+            obs.start_tracing(trace_out, device_counters=True)
         # warm call compiles the mesh program; the timed planner is then
         # cold-started (dense build included), as in bench_planner
         create_planner("equilibrium_batch_sharded", n_shards=n_shards).plan(

@@ -21,8 +21,10 @@ aggregate views the benchmarks and CI assert on:
   records nested under them;
 * ``shards`` (``--shards``) — per-shard tile work from the
   ``batch.shard.*{shard=N}`` counters (the kernel telemetry each mesh
-  participant streams off-device) and the dispatch-vs-sync split of the
-  ``batch.chunk`` spans (how much the pipelined dispatch overlapped).
+  participant streams off-device, recorded under a tracer started with
+  ``device_counters=True``) and the dispatch-vs-sync split of the chunk
+  loop (the ``batch.dispatch`` and ``batch.fetch`` spans; how much the
+  pipelined dispatch overlapped).
 
 ``--validate`` schema-checks the records (exit 1 on problems) and
 ``--chrome OUT`` converts a JSONL trace for Perfetto / chrome://tracing.
@@ -197,9 +199,10 @@ def shard_tables(records: list[dict]) -> tuple[dict, dict]:
     """Sharded-planner views from the trace alone: per-shard tile work
     from the ``batch.shard.*{shard=N}`` footer counters (the kernel's
     on-device telemetry, streamed off with the chunk results) and the
-    dispatch-vs-sync split of every ``batch.chunk`` span (how much of
-    the chunk loop the pipelined dispatch overlapped).  Returns
-    (per-shard rows, chunk rollup)."""
+    dispatch-vs-sync split of the chunk loop: the ``batch.dispatch``
+    spans against the ``batch.fetch`` spans of chunk results, with the
+    share of ``batch.chunk`` spans the pipelined dispatch overlapped.
+    Returns (per-shard rows, chunk rollup)."""
     counters = footer_counters(records)
     per: dict[int, dict] = defaultdict(lambda: {
         "tiles_walked": 0, "cand_tiles": 0, "wins": 0})
@@ -212,14 +215,17 @@ def shard_tables(records: list[dict]) -> tuple[dict, dict]:
     chunks = {"chunks": 0, "overlapped": 0, "dispatch_s": 0.0,
               "sync_s": 0.0, "wall_us": 0.0}
     for r in records:
-        if r.get("ev") != "span" or r.get("name") != "batch.chunk":
+        if r.get("ev") != "span":
             continue
         args = r.get("args", {})
-        chunks["chunks"] += 1
-        chunks["overlapped"] += int(bool(args.get("overlapped")))
-        chunks["dispatch_s"] += args.get("dispatch_s", 0.0)
-        chunks["sync_s"] += args.get("sync_s", 0.0)
-        chunks["wall_us"] += r.get("dur") or 0.0
+        if r["name"] == "batch.chunk":
+            chunks["chunks"] += 1
+            chunks["overlapped"] += int(bool(args.get("overlapped")))
+            chunks["wall_us"] += r.get("dur") or 0.0
+        elif r["name"] == "batch.dispatch":
+            chunks["dispatch_s"] += (r.get("dur") or 0.0) * 1e-6
+        elif r["name"] == "batch.fetch" and args.get("what") == "chunk":
+            chunks["sync_s"] += (r.get("dur") or 0.0) * 1e-6
     return dict(per), chunks
 
 
