@@ -76,6 +76,7 @@ from .cluster import (ClusterDelta, ClusterState, DeviceAddDelta,
                       PoolCreateDelta, PoolGrowthDelta)
 from .equilibrium import EquilibriumConfig, MoveRecord
 from .legality import LegalityState
+from .mirror import ShardMirror
 from .tail import tail_flush, tail_record, tail_stats, tail_terminal
 from .. import obs as _obs
 from ..kernels.select_move import compact_parked, order_key
@@ -681,20 +682,24 @@ def _plan_chunk(dyn, const, slack, headroom, min_dvar, *,
 # Host driver
 
 
-def _pack_rows(rows_on_dev, sh_size: np.ndarray, r_cap: int) -> np.ndarray:
-    """Pad per-device row sets to (n_dev, r_cap), each in the faithful
-    candidate order: size descending, row (= (pg, slot)) ascending."""
-    rows = np.full((len(rows_on_dev), r_cap), -1, np.int32)
-    for d, s in enumerate(rows_on_dev):
-        order = sorted(s, key=lambda r: (-sh_size[r], r))
-        rows[d, :len(order)] = order
-    return rows
+def _pack_rows(sh_dev: np.ndarray, sh_size: np.ndarray, n_dev: int,
+               r_cap: int) -> tuple[np.ndarray, np.ndarray]:
+    """Per-device row tables padded to (n_dev, r_cap), each in the
+    faithful candidate order: size descending, row (= (pg, slot))
+    ascending; and the per-device row counts."""
+    order = np.lexsort((np.arange(len(sh_dev)), -sh_size, sh_dev))
+    nrows = np.bincount(sh_dev, minlength=n_dev)
+    dev = sh_dev[order]
+    pos = np.arange(len(order)) - (np.cumsum(nrows) - nrows)[dev]
+    rows = np.full((n_dev, r_cap), -1, np.int32)
+    rows[dev, pos] = order
+    return rows, nrows.astype(np.int32)
 
 
 class BatchPlanner:
     """Warm-startable handle on the device-resident engine.
 
-    :func:`balance_batch` rebuilt the full dense mirror — DenseState, the
+    :func:`balance_batch` rebuilt the full dense mirror — ShardMirror, the
     packed row tables, the acting table, every device array — on *every*
     call, even when nothing changed since the last plan.  The scenario
     engine (:mod:`repro.sim.engine`) calls the planner every
@@ -825,8 +830,6 @@ class BatchPlanner:
             self._build_mirror()
 
     def _build_mirror(self) -> None:
-        from .equilibrium_jax import DenseState
-
         state, cfg = self.state, self.cfg
         self._stash = []
         self._done = False
@@ -839,55 +842,44 @@ class BatchPlanner:
         if not state.acting or not state.pools or state.n_devices < 2:
             self._epoch = state.mutation_epoch
             return
-        dense = DenseState(state)
-        if not dense.shard_key:
+        dense = ShardMirror(state)
+        if not dense.sh_pg.size:
             self._epoch = state.mutation_epoch
             return
         self._dense = dense
 
-        # compact acting table (n_pg, max pool size), padded with -1
-        n_slots = max(p.size for p in state.pools.values())
-        acting_np = np.full((len(dense.pgs), n_slots), -1, np.int32)
-        for pg, pgi in dense.pg_index.items():
-            osds = state.acting[pg]
-            acting_np[pgi, :len(osds)] = [state.idx(o) for o in osds]
-
-        self._const = (
-            jnp.asarray(dense.cap), jnp.asarray(dense.dev_class, jnp.int32),
-            jnp.asarray(dense.dev_in),
-            jnp.asarray(dense.dev_domain_arr, jnp.int32),
-            jnp.asarray(dense.sh_size.astype(np.float64)),
-            jnp.asarray(dense.sh_pg, jnp.int32),
-            jnp.asarray(dense.sh_pool, jnp.int32),
-            jnp.asarray(dense.sh_class, jnp.int32),
-            jnp.asarray(dense.sh_level, jnp.int32),
-            jnp.asarray(dense.sh_slot, jnp.int32),
-            jnp.asarray(dense.sh_sbase, jnp.int32),
-            jnp.asarray(dense.sh_scnt, jnp.int32),
-            jnp.asarray(dense.ideal),
-        )
-        nrows_np = np.array([len(s) for s in dense.rows_on_dev], np.int32)
-        dst_ok_np = legality.dst_count_ok(dense.pool_counts, dense.ideal,
-                                          cfg.count_slack)
-        order_np = legality.fullest_first(dense.util).astype(np.int32)
+        # every table in its device dtype on the host, then one upload
+        max_rows = int(np.bincount(dense.sh_dev).max())
         self._r_cap = self._round_cap(
-            max(self.row_capacity, int(nrows_np.max()))
-            if self.row_capacity is not None
-            else int(nrows_np.max()) + self.chunk)
-        self._dyn = (
-            jnp.asarray(dense.used), jnp.asarray(dense.util),
-            jnp.asarray(dense.util_sum, jnp.float64),
-            jnp.asarray(dense.util_sumsq, jnp.float64),
-            jnp.asarray(acting_np), jnp.asarray(dense.pool_counts),
-            jnp.asarray(dst_ok_np),
-            jnp.asarray(_pack_rows(dense.rows_on_dev, dense.sh_size,
-                                   self._r_cap)),
-            jnp.asarray(nrows_np), jnp.asarray(order_np),
-        ) + self._fresh_cache(dense.n_dev) \
-            + (jnp.zeros(dense.n_dev, bool),)       # pruned: no bounds yet
-        self._slack = jnp.asarray(cfg.count_slack, jnp.float64)
-        self._headroom = jnp.asarray(cfg.headroom, jnp.float64)
-        self._min_dvar = jnp.asarray(cfg.min_variance_delta, jnp.float64)
+            max(self.row_capacity, max_rows)
+            if self.row_capacity is not None else max_rows + self.chunk)
+        rows_np, nrows_np = _pack_rows(dense.sh_dev, dense.sh_size,
+                                       dense.n_dev, self._r_cap)
+        i32 = np.int32
+        const = (
+            dense.cap, dense.dev_class.astype(i32), dense.dev_in,
+            dense.dev_domain_arr.astype(i32), dense.sh_size,
+            dense.sh_pg.astype(i32), dense.sh_pool.astype(i32),
+            dense.sh_class.astype(i32), dense.sh_level.astype(i32),
+            dense.sh_slot.astype(i32), dense.sh_sbase.astype(i32),
+            dense.sh_scnt.astype(i32), dense.ideal,
+        )
+        dyn = (
+            dense.used, dense.util, np.float64(dense.util_sum),
+            np.float64(dense.util_sumsq),
+            dense.acting_table(dense.sh_dev), dense.pool_counts,
+            legality.dst_count_ok(dense.pool_counts, dense.ideal,
+                                  cfg.count_slack),
+            rows_np, nrows_np,
+            legality.fullest_first(dense.util).astype(i32),
+        )
+        scalars = (np.float64(cfg.count_slack), np.float64(cfg.headroom),
+                   np.float64(cfg.min_variance_delta))
+        (self._const, dyn, (self._slack, self._headroom, self._min_dvar),
+         pruned) = jax.device_put(
+            (const, dyn, scalars, np.zeros(dense.n_dev, bool)))
+        # pruned: no bounds yet
+        self._dyn = dyn + self._fresh_cache(dense.n_dev) + (pruned,)
         self._epoch = state.mutation_epoch
 
     @property
@@ -1005,7 +997,7 @@ class BatchPlanner:
     def _extend_pools(self, created: list[int]) -> None:
         """Append freshly created pools' PGs and shard rows to the host
         mirror's tables, in the exact (sorted pg, slot-major) order a
-        cold DenseState build walks, so an absorbed carry stays bitwise
+        cold ShardMirror build walks, so an absorbed carry stays bitwise
         equal to a rebuilt one (guarded by ``_absorbable``: the new pool
         ids sort after everything already mirrored)."""
         state, dense = self.state, self._dense
@@ -1015,7 +1007,7 @@ class BatchPlanner:
             dense.pool_index[pid] = len(dense.pool_index)
             dense.n_pools = len(dense.pool_index)
             # per-slot rule geometry from the same shared walk
-            # DenseState.__init__ uses (legality.rule_slot_steps)
+            # ShardMirror.__init__ uses (legality.rule_slot_steps)
             geometry = legality.rule_slot_steps(pool.rule)
             new = {"pg": [], "pool": [], "level": [], "class": [],
                    "step": [], "slot": [], "sbase": [], "scnt": []}
@@ -1023,8 +1015,6 @@ class BatchPlanner:
                 dense.pg_index[pg] = len(dense.pg_index)
                 dense.pgs.append(pg)
                 for slot in range(pool.size):
-                    dense.row_of[(pg, slot)] = len(dense.shard_key)
-                    dense.shard_key.append((pg, slot))
                     si, base, scnt, domain, dev_class = geometry[slot]
                     new["pg"].append(dense.pg_index[pg])
                     new["pool"].append(dense.pool_index[pid])
@@ -1104,7 +1094,7 @@ class BatchPlanner:
         if created:
             self._extend_pools(created)
         # per-device legality inputs through the shared LegalityState —
-        # the same construction DenseState.__init__ uses (append-only
+        # the same construction ShardMirror.__init__ uses (append-only
         # device order keeps every existing id, verified by
         # _class_ids_stable; out flips land in dev_in).  Only adds and
         # out-flips can change the device axis, so pure growth /
@@ -1141,32 +1131,20 @@ class BatchPlanner:
         pool_counts = np.stack([state.pool_counts[p] for p in pool_ids]
                                ).astype(np.float64)
         dst_ok = legality.dst_count_ok(pool_counts, ideal, cfg.count_slack)
-        sh_size = np.array([state.shard_sizes[pg]
-                            for pg, _ in dense.shard_key])
+        sh_size = dense.row_sizes(state)
 
         if structural:
-            # canonical row tables straight from the mutated state — the
-            # same (size desc, row asc) order _build's _pack_rows emits;
-            # foreign movements and the discarded stash both collapse to
-            # "re-read the assignment", growth re-sorts implicitly
-            rows_on_dev: list[list[int]] = [[] for _ in range(n_dev)]
-            for osd, shards in state.shards_on.items():
-                d = state.idx(osd)
-                for key in shards:
-                    rows_on_dev[d].append(dense.row_of[key])
-            nrows_np = np.array([len(r) for r in rows_on_dev], np.int32)
-            max_rows = int(nrows_np.max(initial=0))
+            # canonical row tables straight from the mutated state, with
+            # the same helpers _build uses; foreign movements and the
+            # discarded stash both collapse to "re-read the assignment",
+            # growth re-sorts implicitly
+            sh_dev = dense.row_devices(state)
+            max_rows = int(np.bincount(sh_dev, minlength=n_dev).max())
             if max_rows + self.chunk > self._r_cap:
                 self._r_cap = self._round_cap(max_rows + self.chunk)
-            rows_np = _pack_rows(rows_on_dev, sh_size, self._r_cap)
-
-            # acting table from state (width = max pool size, -1 padded)
-            n_slots = max(p.size for p in state.pools.values())
-            acting_np = np.full((len(dense.pgs), n_slots), -1, np.int32)
-            for pg, pgi in dense.pg_index.items():
-                osds = state.acting[pg]
-                acting_np[pgi, :len(osds)] = [state.idx(o) for o in osds]
-            acting = jnp.asarray(acting_np)
+            rows_np, nrows_np = _pack_rows(sh_dev, sh_size, n_dev,
+                                           self._r_cap)
+            acting = jnp.asarray(dense.acting_table(sh_dev))
             shard_const = (
                 jnp.asarray(sh_size.astype(np.float64)),
                 jnp.asarray(dense.sh_pg, jnp.int32),
@@ -1242,11 +1220,6 @@ class BatchPlanner:
         dense.sh_size = sh_size          # Movement sizes read from here
         dense.ideal = ideal
         dense.pool_counts = pool_counts
-        # this is a *partial* refresh — only the fields the device carry
-        # and _reconcile read; membership/occupancy/row-set mirrors stay
-        # at the pre-delta epoch, so the dense engine must refuse to warm
-        # start from this object (DenseState.require_fresh)
-        dense.mirror_complete = False
 
         self._const = dev_const + shard_const + (jnp.asarray(ideal),)
         self._dyn = (
@@ -1318,7 +1291,7 @@ class BatchPlanner:
         with _obs.span("batch.reconcile", cat="batch",
                        moves=len(raw_moves)):
             for row, src, dst, tried, _skipped, secs in raw_moves:
-                pg, slot = dense.shard_key[row]
+                pg, slot = dense.pgs[dense.sh_pg[row]], int(dense.sh_slot[row])
                 mv = Movement(pg, slot, state.devices[src].id,
                               state.devices[dst].id,
                               float(dense.sh_size[row]))

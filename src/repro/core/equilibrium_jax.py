@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from .cluster import ClusterState, Movement
 from .equilibrium import EquilibriumConfig, MoveRecord
 from . import legality
-from .legality import LegalityState
+from .mirror import ShardMirror
 
 
 # ---------------------------------------------------------------------------
@@ -62,109 +62,39 @@ from .legality import LegalityState
 # bitwise from a rebuilt one.
 
 
-class DenseState:
+class DenseState(ShardMirror):
     """Flat array mirror of a :class:`ClusterState`, maintained incrementally.
 
-    Shards are rows of a flat table; PG membership and per-(pg,step) domain
-    occupancy are dense matrices so legality of *all* destinations for *all*
-    source shards is a handful of vectorized ops.
+    Shards are rows of the shared flat table (:class:`ShardMirror`); PG
+    membership and per-(pg,step) domain occupancy are dense matrices so
+    legality of *all* destinations for *all* source shards is a handful
+    of vectorized ops.
     """
 
     def __init__(self, state: ClusterState):
-        self.state = state
-        # freshness contract: the mirror is bit-faithful to the state at
-        # exactly this mutation epoch.  ``apply_row`` callers re-stamp via
+        super().__init__(state)
+        # freshness contract: ``apply_row`` callers re-stamp via
         # ``mark_synced`` after applying the movement to the state; any
-        # other mutation (or a partial refresh like the batch engine's
-        # delta absorption, which leaves the membership/occupancy arrays
-        # untouched) makes ``require_fresh`` refuse warm reuse.
-        self.epoch = state.mutation_epoch
+        # other mutation makes ``require_fresh`` refuse warm reuse
         self.mirror_complete = True
-        devs = state.devices
-        n_dev = len(devs)
-        self.n_dev = n_dev
-
-        # per-device legality inputs (capacities, class ids, domain ids,
-        # in-mask) come from the shared LegalityState; out devices are
-        # never legal destinations (mirrors move_is_legal's out_osds
-        # check, independent of the ideal-count criterion which stops
-        # excluding at count_slack >= 1)
-        self.legality = leg = LegalityState.from_cluster(state)
-        self.cap = leg.cap
-        self.used = state.used()
-        self.class_id = leg.class_id
-        self.dev_class = leg.dev_class
-        self.dev_in = leg.dev_in
-        self.levels = leg.levels
-        self.dev_domain_arr = leg.dev_domain_arr
-        self.n_domains = leg.n_domains
         self.dev_domain = {lvl: self.dev_domain_arr[li]
                            for li, lvl in enumerate(self.levels)}
-
-        # pools
-        pool_ids = sorted(state.pools)
-        self.pool_index = {p: i for i, p in enumerate(pool_ids)}
-        self.n_pools = len(pool_ids)
-        self.ideal = np.stack([state.ideal_shard_count(state.pools[p])
-                               for p in pool_ids])          # (n_pools, n_dev)
-        self.pool_counts = np.stack([state.pool_counts[p] for p in pool_ids]
-                                    ).astype(np.float64)     # (n_pools, n_dev)
-
-        # flat shard table
-        pgs = sorted(state.acting)
-        self.pg_index = {pg: i for i, pg in enumerate(pgs)}
-        self.pgs = pgs
-        n_pg = len(pgs)
-        rows = []
-        for pg in pgs:
-            pool = state.pools[pg[0]]
-            for slot in range(pool.size):
-                rows.append((pg, slot))
-        self.shard_key = rows                                # row -> (pg, slot)
-        self.row_of = {k: r for r, k in enumerate(rows)}
-        n_sh = len(rows)
-        self.sh_pg = np.array([self.pg_index[pg] for pg, _ in rows])
-        self.sh_pool = np.array([self.pool_index[pg[0]] for pg, _ in rows])
-        self.sh_size = np.array([state.shard_sizes[pg] for pg, _ in rows])
-        self.sh_dev = np.array([state.idx(state.acting[pg][slot])
-                                for pg, slot in rows])
-
-        # per-shard rule-step attributes from the shared slot-geometry
-        # walk (legality.rule_slot_steps — also the pool-create
-        # absorption's source, so absorbed rows cannot drift from built
-        # ones)
-        lvl_id = {l: i for i, l in enumerate(self.levels)}
-        geometry = {p: legality.rule_slot_steps(state.pools[p].rule)
-                    for p in state.pools}
-        self.sh_level = np.empty(n_sh, dtype=np.int64)
-        self.sh_class = np.empty(n_sh, dtype=np.int64)       # -1 = any
-        self.sh_step = np.empty(n_sh, dtype=np.int64)        # step idx in pool rule
-        self.sh_slot = np.empty(n_sh, dtype=np.int64)
-        self.sh_sbase = np.empty(n_sh, dtype=np.int64)       # step's first slot
-        self.sh_scnt = np.empty(n_sh, dtype=np.int64)        # step's slot count
-        for r, (pg, slot) in enumerate(rows):
-            si, base, scnt, domain, dev_class = geometry[pg[0]][slot]
-            self.sh_level[r] = lvl_id[domain]
-            self.sh_class[r] = (self.class_id[dev_class]
-                                if dev_class is not None else -1)
-            self.sh_step[r] = si
-            self.sh_slot[r] = slot
-            self.sh_sbase[r] = base
-            self.sh_scnt[r] = scnt
+        self.shard_key = list(zip([self.pgs[i] for i in self.sh_pg.tolist()],
+                                  self.sh_slot.tolist()))   # row -> (pg, slot)
+        n_pg, n_dev = len(self.pgs), self.n_dev
 
         # membership (n_pg, n_dev) and per-(pg,step,level) domain occupancy
         self.member = np.zeros((n_pg, n_dev), dtype=bool)
+        self.member[self.sh_pg, self.sh_dev] = True
         max_steps = max(len(state.pools[p].rule.steps) for p in state.pools)
         self.max_steps = max_steps
         self.occ = {lvl: np.zeros((n_pg, max_steps, self.n_domains[lvl]),
                                   dtype=np.int16) for lvl in self.levels}
-        for r, (pg, slot) in enumerate(rows):
-            pgi = self.pg_index[pg]
-            di = self.sh_dev[r]
-            self.member[pgi, di] = True
-            lvl = self.levels[self.sh_level[r]]
-            self.occ[lvl][pgi, self.sh_step[r],
-                          self.dev_domain[lvl][di]] += 1
+        for li, lvl in enumerate(self.levels):
+            at = self.sh_level == li
+            np.add.at(self.occ[lvl],
+                      (self.sh_pg[at], self.sh_step[at],
+                       self.dev_domain[lvl][self.sh_dev[at]]), 1)
 
         # Per-device domain-occupancy view: occ_dev[pg, step, d] = shards of
         # (pg, step) already in the failure domain containing device d, at
@@ -173,62 +103,18 @@ class DenseState:
         # apply_row.  Each (pg, step) has exactly one failure-domain level
         # (the rule step's), so a single dense array suffices.
         self.occ_dev = np.zeros((n_pg, max_steps, n_dev), dtype=np.int16)
-        pg_pool = np.array([pg[0] for pg in pgs])
-        for p in pool_ids:
+        pg_pool = np.array([pg[0] for pg in self.pgs])
+        for p in sorted(state.pools):
             idx = np.flatnonzero(pg_pool == p)
             for si, rstep in enumerate(state.pools[p].rule.steps):
                 lvl = rstep.failure_domain
                 self.occ_dev[idx, si] = \
                     self.occ[lvl][idx, si][:, self.dev_domain[lvl]]
 
-        # per-device shard rows (python lists; updated incrementally)
+        # per-device shard rows (python sets; updated incrementally)
         self.rows_on_dev: list[set[int]] = [set() for _ in range(n_dev)]
-        for r in range(n_sh):
-            self.rows_on_dev[self.sh_dev[r]].add(r)
-
-        # incremental variance bookkeeping
-        self.util = self.used / self.cap
-        self.util_sum = float(self.util.sum())
-        self.util_sumsq = float((self.util ** 2).sum())
-
-    # -- freshness ----------------------------------------------------------
-
-    @property
-    def stale(self) -> bool:
-        """True when the bound state mutated past the mirrored epoch (or
-        a partial refresh left the mirror structurally incomplete)."""
-        return (not self.mirror_complete
-                or self.epoch != self.state.mutation_epoch)
-
-    def mark_synced(self) -> None:
-        """Re-stamp the mirror as faithful to the state's current epoch —
-        legal only right after the mirror and the state absorbed the same
-        mutation (``apply_row`` + ``ClusterState.apply`` of one move)."""
-        self.epoch = self.state.mutation_epoch
-
-    def require_fresh(self, state: ClusterState) -> None:
-        """Refuse a warm start on a stale or foreign mirror.
-
-        The dense engine's planning math reads the *full* mirror
-        (membership, domain occupancy, per-device row sets); planning on
-        arrays that missed a mutation silently emits illegal or
-        non-faithful moves, so a mismatched epoch is an error, never a
-        fallback.
-        """
-        if state is not self.state:
-            raise ValueError("DenseState warm start bound to a different "
-                             "ClusterState than it mirrors")
-        if not self.mirror_complete:
-            raise RuntimeError(
-                "DenseState mirror is structurally incomplete (a partial "
-                "refresh such as batch delta absorption only updates the "
-                "fields the device carry needs); rebuild before warm "
-                "starting the dense engine")
-        if self.epoch != self.state.mutation_epoch:
-            raise RuntimeError(
-                f"DenseState mirror is stale (mirrored epoch {self.epoch}, "
-                f"state epoch {self.state.mutation_epoch}); rebuild it or "
-                "absorb the missed mutations before warm starting")
+        for r, d in enumerate(self.sh_dev.tolist()):
+            self.rows_on_dev[d].add(r)
 
     # -- mutation -----------------------------------------------------------
 

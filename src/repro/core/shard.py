@@ -648,7 +648,7 @@ class ShardedBatchPlanner(BatchPlanner):
         # already-padded carry computes zero-width pads (idempotent)
         ns = self.n_shards
         self._n_real = n = self.state.n_devices
-        self._rows_real = len(self._dense.shard_key)
+        self._rows_real = len(self._dense.sh_pg)
         self._pgs_real = len(self._dense.pgs)
         w = self._pad_width(n)
         pad = w - int(self._dyn[0].shape[0])

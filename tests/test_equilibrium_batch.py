@@ -303,3 +303,206 @@ def test_out_device_never_a_destination_even_with_count_slack():
     batch, _ = balance_batch(init.copy(), cfg)
     assert as_tuples(batch) == as_tuples(faithful)
     assert all(m.dst_osd != out for m in faithful)
+
+
+# ---------------------------------------------------------------------------
+# the array-built mirror vs the per-row construction it replaced
+
+
+def _hybrid_small_cluster():
+    """Multi-step 1 SSD + 2 HDD rule (cluster D's hybrid geometry) next
+    to a replicated pool, at unit-test size."""
+    from repro.core.cluster import PlacementRule, Pool, RuleStep, TiB
+    from repro.core.clustergen import _make_devices
+    from repro.core.crush import build_cluster
+    devices = _make_devices([(12, 96 * TiB, "hdd"), (6, 12 * TiB, "ssd")],
+                            osds_per_host=2, seed=4)
+    hybrid = PlacementRule.hybrid([RuleStep("ssd", 1, "host"),
+                                   RuleStep("hdd", 2, "host")])
+    pools = [Pool(0, "hyb", 48, hybrid, stored_bytes=6 * TiB),
+             Pool(1, "rbd", 32, PlacementRule.replicated(3, "host", "hdd"),
+                  stored_bytes=10 * TiB)]
+    return build_cluster(devices, pools, seed=4)
+
+
+def _sparse_ids_cluster():
+    """small_test_cluster with OSD ids 7, 10, 13, ... in reversed device
+    order: ids are neither contiguous nor the device index."""
+    import dataclasses
+    from repro.core.cluster import ClusterState
+    base = small_test_cluster()
+    new_id = {d.id: 3 * d.id + 7 for d in base.devices}
+    devices = [dataclasses.replace(d, id=new_id[d.id])
+               for d in reversed(base.devices)]
+    acting = {pg: [new_id[o] for o in osds]
+              for pg, osds in base.acting.items()}
+    return ClusterState(devices, list(base.pools.values()), acting,
+                        base.shard_sizes)
+
+
+def _out_device_cluster():
+    state = small_test_cluster()
+    state.mark_out(state.devices[1].id)
+    return state
+
+
+def _equal_sizes_cluster():
+    """No shard-size jitter: every PG of a pool has the same size, so the
+    row tables' order rests on the row-ascending tie break."""
+    from repro.core.cluster import PlacementRule, Pool, TiB
+    from repro.core.clustergen import _make_devices
+    from repro.core.crush import build_cluster
+    devices = _make_devices([(12, 96 * TiB, "hdd")], osds_per_host=2,
+                            seed=0)
+    r3 = PlacementRule.replicated(3, "host", "hdd")
+    pools = [Pool(0, "rbd", 64, r3, stored_bytes=12 * TiB),
+             Pool(1, "fs", 32, r3, stored_bytes=4 * TiB)]
+    return build_cluster(devices, pools, seed=0, size_jitter=0.0)
+
+
+MIRROR_CLUSTERS = {
+    "small": small_test_cluster,
+    "cluster-a": cluster_a,
+    "hybrid-rule": _hybrid_small_cluster,
+    "sparse-osd-ids": _sparse_ids_cluster,
+    "out-device": _out_device_cluster,
+    "equal-sizes": _equal_sizes_cluster,
+}
+
+
+def _per_row_reference(state):
+    """The per-shard Python construction the array-built mirror replaced
+    (kept as the plain reference): flat table, rule-step attributes,
+    acting table, per-device row sets and packed row tables, plus the
+    dense engine's membership and domain occupancy."""
+    from repro.core import legality
+    leg = legality.LegalityState.from_cluster(state)
+    pgs = sorted(state.acting)
+    pg_index = {pg: i for i, pg in enumerate(pgs)}
+    pool_index = {p: i for i, p in enumerate(sorted(state.pools))}
+    rows = [(pg, slot) for pg in pgs
+            for slot in range(state.pools[pg[0]].size)]
+    ref = {"shard_key": rows}
+    ref["sh_pg"] = np.array([pg_index[pg] for pg, _ in rows])
+    ref["sh_pool"] = np.array([pool_index[pg[0]] for pg, _ in rows])
+    ref["sh_size"] = np.array([state.shard_sizes[pg] for pg, _ in rows])
+    ref["sh_dev"] = np.array([state.idx(state.acting[pg][slot])
+                              for pg, slot in rows])
+    lvl_id = {l: i for i, l in enumerate(leg.levels)}
+    geometry = {p: legality.rule_slot_steps(state.pools[p].rule)
+                for p in state.pools}
+    for name in ("level", "class", "step", "slot", "sbase", "scnt"):
+        ref["sh_" + name] = np.empty(len(rows), dtype=np.int64)
+    for r, (pg, slot) in enumerate(rows):
+        si, base, scnt, domain, dev_class = geometry[pg[0]][slot]
+        ref["sh_level"][r] = lvl_id[domain]
+        ref["sh_class"][r] = (leg.class_id[dev_class]
+                              if dev_class is not None else -1)
+        ref["sh_step"][r] = si
+        ref["sh_slot"][r] = slot
+        ref["sh_sbase"][r] = base
+        ref["sh_scnt"][r] = scnt
+
+    n_slots = max(p.size for p in state.pools.values())
+    acting = np.full((len(pgs), n_slots), -1, np.int32)
+    for pg, pgi in pg_index.items():
+        osds = state.acting[pg]
+        acting[pgi, :len(osds)] = [state.idx(o) for o in osds]
+    ref["acting"] = acting
+    rows_on_dev = [set() for _ in range(state.n_devices)]
+    for r in range(len(rows)):
+        rows_on_dev[ref["sh_dev"][r]].add(r)
+    ref["rows_on_dev"] = rows_on_dev
+    ref["nrows"] = np.array([len(s) for s in rows_on_dev], np.int32)
+    ref["rows"] = []
+    for s in rows_on_dev:
+        ref["rows"].append(sorted(s, key=lambda r: (-ref["sh_size"][r], r)))
+    ref["ideal"] = np.stack([state.ideal_shard_count(state.pools[p])
+                             for p in sorted(state.pools)])
+    ref["pool_counts"] = np.stack([state.pool_counts[p]
+                                   for p in sorted(state.pools)]
+                                  ).astype(np.float64)
+    ref["util"] = state.used() / leg.cap
+
+    n_pg, n_dev = len(pgs), state.n_devices
+    ref["member"] = np.zeros((n_pg, n_dev), dtype=bool)
+    max_steps = max(len(state.pools[p].rule.steps) for p in state.pools)
+    ref["occ"] = {lvl: np.zeros((n_pg, max_steps, leg.n_domains[lvl]),
+                                dtype=np.int16) for lvl in leg.levels}
+    for r, (pg, slot) in enumerate(rows):
+        pgi, di = pg_index[pg], ref["sh_dev"][r]
+        ref["member"][pgi, di] = True
+        lvl = leg.levels[ref["sh_level"][r]]
+        ref["occ"][lvl][pgi, ref["sh_step"][r],
+                        leg.dev_domain(lvl)[di]] += 1
+    ref["occ_dev"] = np.zeros((n_pg, max_steps, n_dev), dtype=np.int16)
+    pg_pool = np.array([pg[0] for pg in pgs])
+    for p in sorted(state.pools):
+        idx = np.flatnonzero(pg_pool == p)
+        for si, rstep in enumerate(state.pools[p].rule.steps):
+            lvl = rstep.failure_domain
+            ref["occ_dev"][idx, si] = \
+                ref["occ"][lvl][idx, si][:, leg.dev_domain(lvl)]
+    return ref
+
+
+def _same(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    return a.dtype == b.dtype and a.shape == b.shape \
+        and a.tobytes() == b.tobytes()
+
+
+@pytest.mark.parametrize("name", sorted(MIRROR_CLUSTERS))
+def test_batch_mirror_matches_per_row_reference(name):
+    """The cold build's device tables are bitwise the per-row
+    construction's, cast to the dtypes the chunk program reads."""
+    import jax
+    from repro.core.equilibrium_batch import BatchPlanner
+    state = MIRROR_CLUSTERS[name]()
+    ref = _per_row_reference(state)
+    bp = BatchPlanner(state, EquilibriumConfig(), chunk=8)
+    with jax.enable_x64(True):
+        bp.sync()
+        const, dyn = jax.device_get((bp._const, bp._dyn))
+    dense = bp._dense
+    for key in ("sh_pg", "sh_pool", "sh_size", "sh_dev", "sh_level",
+                "sh_class", "sh_step", "sh_slot", "sh_sbase", "sh_scnt"):
+        assert _same(getattr(dense, key), ref[key]), key
+    for i, key in enumerate(("sh_pg", "sh_pool", "sh_class", "sh_level",
+                             "sh_slot", "sh_sbase", "sh_scnt"), start=5):
+        assert _same(const[i], ref[key].astype(np.int32)), key
+    assert _same(const[4], ref["sh_size"])
+    assert _same(const[12], ref["ideal"])
+    assert _same(dyn[1], ref["util"])
+    assert _same(dyn[4], ref["acting"])
+    assert _same(dyn[5], ref["pool_counts"])
+    assert _same(dyn[8], ref["nrows"])
+    assert dyn[7].shape == (state.n_devices, bp._r_cap)
+    assert bp._r_cap >= int(ref["nrows"].max()) + bp.chunk
+    for d, want in enumerate(ref["rows"]):
+        assert dyn[7][d, :len(want)].tolist() == want, d
+        assert (dyn[7][d, len(want):] == -1).all(), d
+
+
+@pytest.mark.parametrize("name", sorted(MIRROR_CLUSTERS))
+def test_dense_state_matches_per_row_reference(name):
+    """DenseState builds its flat table through the shared ShardMirror
+    and keeps the legacy engine's own membership, domain occupancy and
+    per-device row sets exactly as the per-row construction gave them."""
+    state = MIRROR_CLUSTERS[name]()
+    ref = _per_row_reference(state)
+    dense = DenseState(state)
+    assert dense.shard_key == ref["shard_key"]
+    for key in ("sh_pg", "sh_pool", "sh_size", "sh_dev", "sh_level",
+                "sh_class", "sh_step", "sh_slot", "sh_sbase", "sh_scnt",
+                "member", "occ_dev"):
+        assert _same(getattr(dense, key), ref[key]), key
+    assert dense.occ.keys() == ref["occ"].keys()
+    for lvl, occ in ref["occ"].items():
+        assert _same(dense.occ[lvl], occ), lvl
+    assert dense.rows_on_dev == ref["rows_on_dev"]
+    assert all(type(r) is int for s in dense.rows_on_dev for r in s)
+    assert _same(dense.ideal, ref["ideal"])
+    assert _same(dense.pool_counts, ref["pool_counts"])
+    assert _same(dense.util, ref["util"])
+    assert not dense.stale
