@@ -1278,18 +1278,60 @@ class BatchPlanner:
         stats_out["pipeline"] = self.pipeline
         self._registry_stats(snap, stats_out)
 
+    def _record_move_classes(self, raw_moves,
+                             dst_width: int | None = None) -> None:
+        """Per-plan account of the moved rows' rule-step classes and of
+        the destination axis the chunk program evaluated for them, from
+        array operations over the moves' rows (one registry call per
+        class, never one per move):
+
+        * ``batch.moves_by_class{cls=<class>}``: moves by the rule-step
+          class of the moved row (``any`` for a step with no class);
+        * ``batch.dst_cols``: summed over the moves, the width of the
+          destination axis of the row's legality tile in
+          :func:`_plan_chunk` — ``eval_static`` and ``eval_cand`` evaluate
+          every column of the dispatched carry's device axis for every
+          row of a walked tile: ``n_dev``, pads of a sharded carry
+          included, or ``dst_width`` where the moves were planned over
+          another carry (a fleet lane's bucket);
+        * ``batch.dst_cols_live``: summed over the moves, how many of
+          those columns :func:`legality.class_ok` admits for the row's
+          class — the devices of that class, or every column for a step
+          with no class (pad devices hold class -2, which no step names).
+
+        A chunk program that narrows its destination axis has to change
+        both definitions with it."""
+        dense = self._dense
+        dst_width = dst_width or int(self._const[0].shape[0])
+        rows = np.fromiter((m[0] for m in raw_moves), np.int64,
+                           len(raw_moves))
+        cls, counts = np.unique(dense.sh_class[rows], return_counts=True)
+        dev_axis = np.full(dst_width, -2, np.int64)
+        dev_axis[:dense.n_dev] = dense.dev_class
+        name_of = {i: c for c, i in dense.class_id.items()}
+        reg = _obs_registry()
+        live = 0
+        for c, n in zip(cls.tolist(), counts.tolist()):
+            reg.inc("batch.moves_by_class", n, cls=name_of.get(c, "any"))
+            live += n * int(legality.class_ok(c, dev_axis).sum())
+        reg.inc("batch.dst_cols", dst_width * len(rows))
+        reg.inc("batch.dst_cols_live", live)
+
     def _reconcile(self, raw_moves, record_trajectory: bool,
-                   record_free_space: bool
+                   record_free_space: bool, dst_width: int | None = None
                    ) -> tuple[list[Movement], list["MoveRecord"]]:
         """Replay the emitted move log through :meth:`ClusterState.apply`
         (which re-validates every source assignment), exactly like
         :func:`repro.core.simulate.simulate` replays movement logs, then
-        mark the carry synced to the resulting epoch."""
+        mark the carry synced to the resulting epoch.  ``dst_width``:
+        see :meth:`_record_move_classes`."""
         dense, state = self._dense, self.state
         movements: list[Movement] = []
         records: list[MoveRecord] = []
         with _obs.span("batch.reconcile", cat="batch",
                        moves=len(raw_moves)):
+            if raw_moves:
+                self._record_move_classes(raw_moves, dst_width)
             for row, src, dst, tried, _skipped, secs in raw_moves:
                 pg, slot = dense.pgs[dense.sh_pg[row]], int(dense.sh_slot[row])
                 mv = Movement(pg, slot, state.devices[src].id,

@@ -466,8 +466,10 @@ class FleetPlanner:
                 with _obs.span("planner.plan", cat="planner", counters=True,
                                planner=self.name, cluster=str(key)) as psp:
                     t0 = time.perf_counter()
+                    where = self._pack.where.get(key)
                     movements, records = bp._reconcile(
-                        raw[key], record_trajectory, record_free_space)
+                        raw[key], record_trajectory, record_free_space,
+                        dst_width=where[0].n_dev if where else None)
                     stats: dict = {}
                     acc = tail_stats(stats)
                     for _row, _src, _dst, tried, skipped, secs in raw[key]:

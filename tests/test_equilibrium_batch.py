@@ -506,3 +506,85 @@ def test_dense_state_matches_per_row_reference(name):
     assert _same(dense.pool_counts, ref["pool_counts"])
     assert _same(dense.util, ref["util"])
     assert not dense.stale
+
+
+# ---------------------------------------------------------------------------
+# the per-plan account of moved classes and of the destination axis
+
+
+def _hand_count(state, moves, width):
+    """(moves by class name, summed destination columns, summed columns
+    the moved rows' rule-step classes admit), from the cluster alone."""
+    from repro.core.legality import rule_slot_steps
+    by_class, live = {}, 0
+    for m in moves:
+        cls = rule_slot_steps(state.pools[m.pg[0]].rule)[m.slot][4]
+        name = cls if cls is not None else "any"
+        by_class[name] = by_class.get(name, 0) + 1
+        live += (width if cls is None else
+                 sum(d.device_class == cls for d in state.devices))
+    return by_class, width * len(moves), live
+
+
+def _class_counters(deltas):
+    prefix = "batch.moves_by_class{cls="
+    return ({k[len(prefix):-1]: v for k, v in deltas.items()
+             if k.startswith(prefix)},
+            deltas.get("batch.dst_cols", 0),
+            deltas.get("batch.dst_cols_live", 0))
+
+
+@pytest.mark.parametrize("planner,kwargs,pad", [
+    ("equilibrium_batch", {}, 0),
+    ("equilibrium_batch_sharded", {"n_shards": 1}, 5),
+])
+def test_move_class_counters_match_a_hand_count(planner, kwargs, pad):
+    """Once per plan, over the moved rows: moves by rule-step class, and
+    the destination columns of each moved row's tile with the share its
+    class admits.  The sharded carry evaluates its pad columns too."""
+    from repro.core.planner import create_planner
+    from repro.obs import registry
+    initial = _hybrid_small_cluster()
+    width = initial.n_devices + pad
+    if pad:
+        kwargs = dict(kwargs, pad_devices=width)
+    reg = registry()
+    snap = reg.snapshot()
+    state = initial.copy()
+    result = create_planner(planner, **kwargs).plan(state)
+    moves = result.moves
+    assert moves
+    by_class, cols, live = _class_counters(reg.deltas_since(snap))
+    assert sum(by_class.values()) == len(moves)
+    assert set(by_class) == {"ssd", "hdd"}
+    assert (by_class, cols, live) == _hand_count(initial, moves, width)
+    assert 0 < live < cols
+
+
+def test_move_class_counters_count_a_classless_step_as_all_columns():
+    from repro.core.planner import create_planner
+    from repro.obs import registry
+    state = cluster_a()
+    assert all(s.device_class is None for p in state.pools.values()
+               for s in p.rule.steps)
+    reg = registry()
+    snap = reg.snapshot()
+    moves = create_planner("equilibrium_batch").plan(state).moves
+    by_class, cols, live = _class_counters(reg.deltas_since(snap))
+    assert by_class == {"any": len(moves)}
+    assert cols == live == len(moves) * state.n_devices
+
+
+@pytest.mark.parametrize("tracer", [None, {}, {"device_counters": True}])
+def test_hybrid_moves_identical_with_the_tracer_on_and_off(tracer):
+    from repro import obs
+    from repro.core.planner import create_planner
+    off = create_planner("equilibrium_batch").plan(_hybrid_small_cluster())
+    if tracer is None:
+        on = create_planner("equilibrium_batch").plan(
+            _hybrid_small_cluster())
+    else:
+        with obs.tracing(**tracer):
+            on = create_planner("equilibrium_batch").plan(
+                _hybrid_small_cluster())
+    assert off.moves and as_tuples(on.moves) == as_tuples(off.moves)

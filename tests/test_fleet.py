@@ -302,6 +302,22 @@ def test_fleet_planner_is_registered():
     assert res.stats["fleet_clusters"] == 1
 
 
+def test_fleet_lane_counts_the_bucket_width_as_its_destination_axis():
+    """A lane's chunk program evaluates the bucket's padded device axis:
+    9 devices pad to 16 columns for every move."""
+    from repro.obs import registry
+    reg = registry()
+    snap = reg.snapshot()
+    a, _ = _twin_pair(9, 6)
+    moves = create_planner("fleet", chunk=CH, row_block=RB).plan(
+        a, budget=CH).moves
+    d = reg.deltas_since(snap)
+    assert moves and a.n_devices == 9
+    assert d["batch.dst_cols"] == 16 * len(moves)
+    assert sum(v for k, v in d.items()
+               if k.startswith("batch.moves_by_class")) == len(moves)
+
+
 def test_fleet_service_tick_and_ingest():
     svc = FleetService(chunk=CH, row_block=RB)
     a, b = _twin_pair(9, 7)
