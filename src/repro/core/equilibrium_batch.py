@@ -28,9 +28,12 @@ blocking ``bool(found)`` device sync per source.  This module moves the
   every fuller source is resolved (found or exhausted), i.e. the loop
   runs until ``min(found sources) < min(unresolved sources)``.  With
   ``source_block=cfg.k`` and ``row_block ≥ max rows/device`` this is the
-  full ``(k, R_max, n_dev)`` tensor in one iteration; the defaults use a
-  small tile because the fullest source almost always yields the move —
-  same move sequence either way, property-tested across tile shapes.
+  full ``(k, R_max, n_dev)`` tensor in one iteration.  The default tile
+  is one source × :data:`ROW_BLOCK` rows: where the fullest source's
+  first rows yield the move (cluster E) one step decides it, and where
+  many rows and sources come up empty first (clusters B and D) the walk
+  takes several steps, each evaluated whole — the same move sequence
+  for every tile, property-tested across tile shapes.
 * **The inner masked-argmax/argmin reduction is a kernel** —
   :func:`repro.kernels.ops.masked_select` (Pallas, compiled on a TPU
   over an exact int32 order key; the pure-jnp reference on CPU),
@@ -181,15 +184,19 @@ def _plan_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, k_eff,
     convergence tail skips fruitless sources without touching their
     legality tiles.
 
-    Returns (dyn', done, overflow, tel, moves (m, 5) int32) where each
-    move row is (shard_row, src_idx, dst_idx, sources_tried, bound_skips)
-    or -1 sentinels; ``sources_tried`` counts ranks in the *full*
-    fullest-first order (identical with and without ``bounds``) and
-    ``bound_skips`` of those ranks were skipped by live certificates.
+    Returns (dyn', done, overflow, tel, moves (m, 5) int32, steps (m,)
+    int32) where each move row is (shard_row, src_idx, dst_idx,
+    sources_tried, bound_skips) or -1 sentinels; ``sources_tried`` counts
+    ranks in the *full* fullest-first order (identical with and without
+    ``bounds``) and ``bound_skips`` of those ranks were skipped by live
+    certificates.  ``steps`` counts the walk's iterations at each scan
+    step, the fruitless final walk and an overflowing move's walk
+    included (0 where the step is inactive) — always on, one add per
+    iteration.
 
     ``tel`` is the device-side telemetry vector (int32[4]: legality
-    tiles walked, tiles holding a candidate, legality-cache hits,
-    legality-cache misses), populated only under the static ``telemetry``
+    tiles walked, i.e. the sum of ``steps``, tiles holding a candidate,
+    legality-cache hits, legality-cache misses), populated only under the static ``telemetry``
     flag — the disabled variant compiles the counter updates away
     entirely, so tracing can never perturb the move sequence (it only
     ever reads).  The host fetches it with the same per-chunk sync that
@@ -330,7 +337,7 @@ def _plan_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, k_eff,
 
         def body(carry):
             (sb, c, found_row, found_dst, win_j, win_row, win_dst, done,
-             c_dev, c_ok, c_clean, marg, pruned, tel) = carry
+             c_dev, c_ok, c_clean, marg, pruned, tel, steps) = carry
             src_b = lax.dynamic_slice_in_dim(src_order, sb * kb, kb)
             if cached:
                 zero = jnp.int32(0)
@@ -369,8 +376,8 @@ def _plan_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, k_eff,
             else:
                 cand = eval_cand(sb, c)
             any_rows = jnp.any(cand, axis=(1, 2))            # (kb,)
+            steps = steps + 1
             if telemetry:
-                tel = tel.at[0].add(1)
                 tel = tel.at[1].add(jnp.any(any_rows).astype(jnp.int32))
             # the variance test + masked-select reduction only run when
             # the tile holds a candidate at all; the convergence-tail
@@ -432,7 +439,7 @@ def _plan_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, k_eff,
             marg = jnp.where(exhausted, False, marg)
             return (next_sb, next_c, found_row, found_dst,
                     win_j, win_row, win_dst, done, c_dev, c_ok, c_clean,
-                    marg, pruned, tel)
+                    marg, pruned, tel, steps)
 
         def cond(carry):
             return active & ~carry[7]
@@ -440,11 +447,13 @@ def _plan_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, k_eff,
         init = (jnp.int32(0), jnp.int32(0), jnp.full((kb,), -1, jnp.int32),
                 jnp.zeros((kb,), jnp.int32), jnp.int32(-1), jnp.int32(-1),
                 jnp.int32(0), jnp.bool_(False), c_dev, c_ok, c_clean,
-                jnp.zeros((kb,), bool), pruned, tel)
+                jnp.zeros((kb,), bool), pruned, tel, jnp.int32(0))
         out = lax.while_loop(cond, body, init)
         win_j, win_row, win_dst = out[4], out[5], out[6]
         dyn = dyn[:10] + (out[8], out[9], out[10], out[12])
-        tel = out[13]
+        tel, steps = out[13], out[14]
+        if telemetry:                   # tiles walked = the walk's steps
+            tel = tel.at[0].add(steps)
         found = win_j >= 0
         jw = jnp.clip(win_j, 0, k_pad - 1)
         win_dev = src_order[jw]
@@ -463,7 +472,8 @@ def _plan_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, k_eff,
                 rank + 1,
                 rank - jw,
                 dyn,
-                tel)
+                tel,
+                steps)
 
     def reorder(order, util, src, dst):
         """Re-sort ``src`` and ``dst`` within the maintained stable
@@ -622,7 +632,7 @@ def _plan_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, k_eff,
     def step(carry, _):
         dyn, done, overflow, tel = carry
         active = ~(done | overflow)
-        found, row, src, dst, tried, skipped, dyn, tel = \
+        found, row, src, dst, tried, skipped, dyn, tel, steps = \
             select_one(dyn, active, tel)
         # a full destination row-list would drop a shard: stop the chunk
         # and let the host re-pad (never hit when row_capacity >= max
@@ -634,13 +644,13 @@ def _plan_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, k_eff,
                          jnp.full((5,), -1, jnp.int32))
         done = done | (active & ~found)
         overflow = overflow | ovf
-        return (dyn, done, overflow, tel), emit
+        return (dyn, done, overflow, tel), (emit, steps)
 
     carry0 = (dyn, ~active0, jnp.bool_(False),
               jnp.zeros((4,), jnp.int32))
-    (dyn, done, overflow, tel), moves = lax.scan(step, carry0, None,
-                                                 length=m)
-    return dyn, done, overflow, tel, moves
+    (dyn, done, overflow, tel), (moves, steps) = lax.scan(
+        step, carry0, None, length=m)
+    return dyn, done, overflow, tel, moves, steps
 
 
 #: The chunk carry is donated to the jit call (``donate_argnums``): the
@@ -648,6 +658,15 @@ def _plan_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, k_eff,
 #: per dispatch.  Structural (not a knob) — exported so benchmarks can
 #: record the variant honestly in derived fields.
 DONATED_CARRY = True
+
+#: Rows of a legality tile, the rows of each source one step of the walk
+#: evaluates, where the caller gives no ``row_block``.  The walk's device
+#: time is its step count times a step's cost; 32 rows take a third of
+#: the 8-row tile's steps a move on the paper's clusters B and D and
+#: about as many on E, where the fullest source's first rows yield the
+#: move.  The choice assumes a 32-row step costs about what an 8-row
+#: one does on the chip (PERF.md §6).
+ROW_BLOCK = 32
 
 
 @partial(jax.jit, static_argnames=("k", "kb", "rb", "m", "backend", "cached",
@@ -670,12 +689,12 @@ def _plan_chunk(dyn, const, slack, headroom, min_dvar, *,
     ``nrows`` vector for the re-pad check, keeping the per-chunk sync
     payload O(chunk) — and free of references into the donated carry."""
     n_dev = const[0].shape[0]
-    dyn, done, overflow, tel, moves = _plan_chunk_impl(
+    dyn, done, overflow, tel, moves, steps = _plan_chunk_impl(
         dyn, const, slack, headroom, min_dvar,
         jnp.asarray(float(n_dev), jnp.float64), jnp.int32(k),
         jnp.bool_(True), k=k, kb=kb, rb=rb, m=m, backend=backend,
         cached=cached, bounds=bounds, telemetry=telemetry)
-    return dyn, done, overflow, tel, moves, jnp.max(dyn[8])
+    return dyn, done, overflow, tel, moves, steps, jnp.max(dyn[8])
 
 
 # ---------------------------------------------------------------------------
@@ -746,7 +765,7 @@ class BatchPlanner:
 
     def __init__(self, state: ClusterState,
                  cfg: EquilibriumConfig | None = None, chunk: int = 64,
-                 source_block: int = 1, row_block: int = 8,
+                 source_block: int = 1, row_block: int | None = None,
                  row_capacity: int | None = None,
                  select_backend: str = "auto",
                  legality_cache: bool = False,
@@ -776,7 +795,7 @@ class BatchPlanner:
         self.select_backend = select_backend
         self._k = min(self.cfg.k, max(state.n_devices, 1))
         self._kb = min(max(1, source_block), self._k)
-        self._rb = max(1, row_block)
+        self._rb = ROW_BLOCK if row_block is None else max(1, row_block)
         self._dense = None
         self._dyn = None
         self._epoch = -1                # state.mutation_epoch at last sync
@@ -1385,7 +1404,7 @@ class BatchPlanner:
         *unfetched* handles so the caller chooses when to block.  The
         sharded engine overrides this with the mesh dispatch."""
         jit0 = _plan_chunk._cache_size()
-        self._dyn, done, overflow, tel, moves, nmax = _plan_chunk(
+        self._dyn, done, overflow, tel, moves, steps, nmax = _plan_chunk(
             self._dyn, self._const, self._slack, self._headroom,
             self._min_dvar, k=self._k, kb=self._kb, rb=self._rb,
             m=self.chunk, backend=self.select_backend,
@@ -1394,7 +1413,7 @@ class BatchPlanner:
         recompiles = _plan_chunk._cache_size() - jit0
         if recompiles:
             _obs_registry().inc("batch.jit_recompiles", recompiles)
-        return (moves, done, overflow, tel, nmax), recompiles
+        return (moves, steps, done, overflow, tel, nmax), recompiles
 
     def _record_chunk_tel(self, reg, tel_np) -> None:
         """Fold one fetched device-telemetry vector into the registry
@@ -1410,7 +1429,9 @@ class BatchPlanner:
         """Run chunks until ``budget`` raw moves are on hand (stashing any
         overshoot), the device reports convergence, or a re-pad is needed.
         ``self._terminal_seconds`` collects the wall time of chunks that
-        emit no moves (the terminal every-source-fruitless scan).
+        emit no moves (the terminal every-source-fruitless scan).  The
+        walk's iterations come back with each chunk's moves and are
+        added to ``batch.walk_steps`` once per call.
 
         With ``pipeline`` on (the default), chunk *i+1* is async-dispatched
         as soon as chunk *i*'s fetched scalars prove another full chunk is
@@ -1435,6 +1456,7 @@ class BatchPlanner:
         # bit-identity trivially
         telemetry = _obs.device_counters()
         pending = None      # (handles, recompiles) of chunk i+1
+        walk_steps = 0
         while len(raw) < budget and not self._done:
             with _obs.span("batch.chunk", cat="batch") as sp:
                 t0 = time.perf_counter()
@@ -1445,10 +1467,11 @@ class BatchPlanner:
                     handles, recompiles = pending
                     pending = None
                     overlapped = True
-                moves_np, done, overflow, tel_np, nmax = _fetch(handles,
-                                                                "chunk")
+                moves_np, steps_np, done, overflow, tel_np, nmax = _fetch(
+                    handles, "chunk")
                 dt = time.perf_counter() - t0
                 done, overflow, nmax = bool(done), bool(overflow), int(nmax)
+                walk_steps += int(steps_np.sum())
                 emitted = moves_np[moves_np[:, 0] >= 0]
                 if (self.pipeline and not done and not overflow
                         and len(raw) + len(emitted) < budget
@@ -1517,6 +1540,8 @@ class BatchPlanner:
                 self._dyn = self._dyn[:7] + (jnp.asarray(packed),) \
                     + self._dyn[8:10] + self._fresh_cache(n_carry) \
                     + (self._dyn[13],)
+        if walk_steps:
+            reg.inc("batch.walk_steps", walk_steps)
         return raw
 
     def plan(self, max_moves: int | None = None,
@@ -1558,7 +1583,7 @@ class BatchPlanner:
 def _balance_batch(state: ClusterState, cfg: EquilibriumConfig | None = None,
                    record_trajectory: bool = False,
                    record_free_space: bool = True, chunk: int = 64,
-                   source_block: int = 1, row_block: int = 8,
+                   source_block: int = 1, row_block: int | None = None,
                    row_capacity: int | None = None,
                    select_backend: str = "auto",
                    legality_cache: bool = False,
@@ -1572,8 +1597,8 @@ def _balance_batch(state: ClusterState, cfg: EquilibriumConfig | None = None,
     ``source_block`` × ``row_block`` is the tile of the batched
     ``(k, R_max, n_dev)`` legality tensor evaluated per inner iteration
     (``source_block=cfg.k`` + ``row_block >= R_max`` evaluates the whole
-    tensor at once; the defaults walk it lazily because the fullest
-    source usually yields the move).  ``row_capacity`` pads the
+    tensor at once); without ``row_block`` each step evaluates
+    :data:`ROW_BLOCK` rows of one source.  ``row_capacity`` pads the
     per-device row table (default: max shards/device + ``chunk``, the
     no-overflow invariant).  ``select_backend``: "auto" (the compiled
     Pallas kernel on a TPU, the jnp reference elsewhere), "ref",
@@ -1611,7 +1636,7 @@ def _balance_batch(state: ClusterState, cfg: EquilibriumConfig | None = None,
 def balance_batch(state: ClusterState, cfg: EquilibriumConfig | None = None,
                   record_trajectory: bool = False,
                   record_free_space: bool = True, chunk: int = 64,
-                  source_block: int = 1, row_block: int = 8,
+                  source_block: int = 1, row_block: int | None = None,
                   row_capacity: int | None = None,
                   select_backend: str = "auto",
                   legality_cache: bool = False,

@@ -362,7 +362,7 @@ class BatchEquilibriumPlanner:
     engine = "batch"                     # PlanResult.stats["engine"]
 
     def __init__(self, cfg: EquilibriumConfig | None = None, chunk: int = 64,
-                 source_block: int = 1, row_block: int = 8,
+                 source_block: int = 1, row_block: int | None = None,
                  row_capacity: int | None = None,
                  select_backend: str = "auto", warm: bool = True,
                  legality_cache: bool = False, source_bounds: bool = True,

@@ -109,6 +109,8 @@ def _shard_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, *,
     replicated (every shard walks the tiles in lockstep), the last two
     are this shard's share of the load, the skew signal
     ``tools/tracestat.py --shards`` tabulates.
+    ``steps``, the walk's iterations per scan step, is replicated like
+    the serial engine's.
     """
     (cap, dev_class, dev_in, dev_domain, sh_size, sh_pg, sh_pool,
      sh_class, sh_level, sh_slot, sh_sbase, sh_scnt, ideal) = const
@@ -266,15 +268,15 @@ def _shard_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, *,
 
         def body(carry):
             (sb, c, found_row, found_dst, win_j, win_row, win_dst, done,
-             marg, pruned, tel) = carry
+             marg, pruned, tel, steps) = carry
             src_b = lax.dynamic_slice_in_dim(src_order, sb * kb, kb)
             cand = eval_cand(sb, c)                   # (kb, rb, n_local)
             any_local = jnp.any(cand, axis=(1, 2))    # this shard's share
             # the certificate predicate needs every shard's candidates
             any_rows = legality.shard_any(
                 lax.psum(any_local.astype(i32), axis))           # (kb,)
+            steps = steps + 1
             if telemetry:
-                tel = tel.at[0].add(1)
                 tel = tel.at[1].add(jnp.any(any_rows).astype(i32))
                 tel = tel.at[2].add(jnp.any(any_local).astype(i32))
             # dead-tile short-circuit on the *global* any bit — replicated,
@@ -349,7 +351,7 @@ def _shard_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, *,
             found_dst = jnp.where(exhausted, 0, found_dst)
             marg = jnp.where(exhausted, False, marg)
             return (next_sb, next_c, found_row, found_dst,
-                    win_j, win_row, win_dst, done, marg, pruned, tel)
+                    win_j, win_row, win_dst, done, marg, pruned, tel, steps)
 
         def cond(carry):
             return active & ~carry[7]
@@ -357,11 +359,13 @@ def _shard_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, *,
         init = (jnp.int32(0), jnp.int32(0), jnp.full((kb,), -1, jnp.int32),
                 jnp.zeros((kb,), jnp.int32), jnp.int32(-1), jnp.int32(-1),
                 jnp.int32(0), jnp.bool_(False), jnp.zeros((kb,), bool),
-                pruned, tel)
+                pruned, tel, jnp.int32(0))
         out = lax.while_loop(cond, body, init)
         win_j, win_row, win_dst = out[4], out[5], out[6]
         dyn = dyn[:13] + (out[9],)
-        tel = out[10]
+        tel, steps = out[10], out[11]
+        if telemetry:                   # tiles walked = the walk's steps
+            tel = tel.at[0].add(steps)
         found = win_j >= 0
         jw = jnp.clip(win_j, 0, k_pad - 1)
         win_dev = src_order[jw]
@@ -376,7 +380,8 @@ def _shard_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, *,
                 rank + 1,
                 rank - jw,
                 dyn,
-                tel)
+                tel,
+                steps)
 
     def reorder(order, util, src, dst):
         """Verbatim serial re-sort — `order`/`util` are replicated, so
@@ -485,7 +490,7 @@ def _shard_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, *,
     def step(carry, _):
         dyn, done, overflow, tel = carry
         active = ~(done | overflow)
-        found, row, src, dst, tried, skipped, dyn, tel = \
+        found, row, src, dst, tried, skipped, dyn, tel, steps = \
             select_one(dyn, active, tel)
         owns_d = legality.shard_owns(dst, base, n_local)
         nr_dst = gather_at(dyn[8], dst, owns_d, base)
@@ -496,14 +501,14 @@ def _shard_chunk_impl(dyn, const, slack, headroom, min_dvar, n_real, *,
                          jnp.full((5,), -1, jnp.int32))
         done = done | (active & ~found)
         overflow = overflow | ovf
-        return (dyn, done, overflow, tel), emit
+        return (dyn, done, overflow, tel), (emit, steps)
 
     carry0 = (dyn, jnp.bool_(False), jnp.bool_(False),
               jnp.zeros((4,), jnp.int32))
-    (dyn, done, overflow, tel), moves = lax.scan(step, carry0, None,
-                                                 length=m)
+    (dyn, done, overflow, tel), (moves, steps) = lax.scan(
+        step, carry0, None, length=m)
     nmax = lax.pmax(jnp.max(dyn[8]), axis)
-    return dyn, done, overflow, tel[None, :], moves, nmax
+    return dyn, done, overflow, tel[None, :], moves, steps, nmax
 
 
 #: replicated spec shared by every non-sharded leaf
@@ -541,7 +546,7 @@ def shard_chunk_program(mesh: Mesh):
         return jax.shard_map(
             body, mesh=mesh,
             in_specs=(_DYN_SPECS, _CONST_SPECS, _R, _R, _R, _R),
-            out_specs=(_DYN_SPECS, _R, _R, P("dev"), _R, _R),
+            out_specs=(_DYN_SPECS, _R, _R, P("dev"), _R, _R, _R),
             check_vma=False,
         )(dyn, const, slack, headroom, min_dvar, n_real)
 
@@ -694,7 +699,7 @@ class ShardedBatchPlanner(BatchPlanner):
     def _dispatch_chunk(self, telemetry: bool):
         fn = _shard_chunk_fn(self.n_shards)
         jit0 = fn._cache_size()
-        self._dyn, done, overflow, tel, moves, nmax = fn(
+        self._dyn, done, overflow, tel, moves, steps, nmax = fn(
             self._dyn, self._const, self._slack, self._headroom,
             self._min_dvar, jnp.asarray(float(self._n_real), jnp.float64),
             k=self._k, kb=self._kb, rb=self._rb, m=self.chunk,
@@ -703,7 +708,7 @@ class ShardedBatchPlanner(BatchPlanner):
         recompiles = fn._cache_size() - jit0
         if recompiles:
             _obs_registry().inc("batch.jit_recompiles", recompiles)
-        return (moves, done, overflow, tel, nmax), recompiles
+        return (moves, steps, done, overflow, tel, nmax), recompiles
 
     def _record_chunk_tel(self, reg, tel_np) -> None:
         tel = np.asarray(tel_np)
@@ -782,7 +787,7 @@ class ShardedBatchEquilibriumPlanner(BatchEquilibriumPlanner):
     engine = "batch-sharded"
 
     def __init__(self, cfg: EquilibriumConfig | None = None, chunk: int = 64,
-                 source_block: int = 1, row_block: int = 8,
+                 source_block: int = 1, row_block: int | None = None,
                  row_capacity: int | None = None, warm: bool = True,
                  source_bounds: bool = True, pipeline: bool = True,
                  n_shards: int | None = None,

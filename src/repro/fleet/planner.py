@@ -74,7 +74,8 @@ def _plan_fleet_chunk(dyn, const, slack, headroom, min_dvar, n_real, k_eff,
     fodder rather than copies."""
     impl = partial(_plan_chunk_impl, k=k, kb=kb, rb=rb, m=m, backend=backend,
                    cached=cached, bounds=bounds, telemetry=telemetry)
-    dyn, done, overflow, tel, moves = jax.vmap(impl)(
+    # the walk's per-step iteration counts are not fetched
+    dyn, done, overflow, tel, moves, _steps = jax.vmap(impl)(
         dyn, const, slack, headroom, min_dvar, n_real, k_eff, active0)
     # per-lane row high-water mark, fused into the same program so the
     # overflow check costs no extra eager op per round

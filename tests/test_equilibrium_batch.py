@@ -86,18 +86,52 @@ def test_batch_matches_faithful_config_variants(kwargs):
     assert as_tuples(a) == as_tuples(b)
 
 
-@pytest.mark.parametrize("source_block,row_block", [
-    (1, 1),          # minimal tiles
-    (3, 5),          # ragged blocks (k=25 not a multiple of 3)
-    (25, 64),        # the full (k, R_max, n_dev) tensor in one iteration
+@pytest.mark.parametrize("source_block,row_block,row_capacity,cluster", [
+    pytest.param(1, 1, None, "small", id="1-1"),       # minimal tiles
+    # ragged blocks (k=25 not a multiple of 3)
+    pytest.param(3, 5, None, "small", id="3-5"),
+    # the full (k, R_max, n_dev) tensor in one iteration
+    pytest.param(25, 64, None, "small", id="25-64"),
+    # the engine's default tile, on the single-step rules and on the
+    # hybrid multi-step one (rows per device: 6-36 and 4-23)
+    pytest.param(1, None, None, "small", id="default-small"),
+    pytest.param(1, None, None, "hybrid", id="default-hybrid"),
+    *(pytest.param(1, rb, None, "hybrid", id=f"1-{rb}-hybrid")
+      for rb in (8, 16, 64)),
+    # a width that leaves a short last tile of a source's rows
+    pytest.param(1, 13, None, "hybrid", id="1-13-hybrid"),
+    pytest.param(2, None, None, "hybrid", id="2-default-hybrid"),
+    # the row table as tight as it rounds: destination lists fill up
+    # mid-chunk and the host re-pads
+    pytest.param(1, None, 1, "hybrid", id="default-tight-hybrid"),
+    pytest.param(1, 13, 1, "hybrid", id="1-13-tight-hybrid"),
 ])
-def test_batch_tile_shapes_identical(source_block, row_block):
-    """Tile shape is a performance knob, never a semantics knob."""
+def test_batch_tile_shapes_identical(source_block, row_block, row_capacity,
+                                     cluster):
+    """Tile shape is a performance knob, never a semantics knob: every
+    tile plans the faithful sequence, on the single-step rules and on
+    the hybrid multi-step one."""
+    make = {"small": small_test_cluster,
+            "hybrid": _hybrid_small_cluster}[cluster]
     cfg = EquilibriumConfig()
-    a, _ = equilibrium_balance(small_test_cluster(), cfg)
-    b, _ = balance_batch(small_test_cluster(), cfg,
-                         source_block=source_block, row_block=row_block)
-    assert as_tuples(a) == as_tuples(b)
+    a, _ = equilibrium_balance(make(), cfg)
+    b, _ = balance_batch(make(), cfg, source_block=source_block,
+                         row_block=row_block, row_capacity=row_capacity)
+    assert a and as_tuples(a) == as_tuples(b)
+
+
+def test_batch_row_block_defaults_to_the_engine_tile():
+    """Without a ``row_block`` the batch engine and the sharded engine
+    walk :data:`ROW_BLOCK` rows a step; a caller's ``row_block`` keeps
+    its meaning, and the row table is a whole number of tiles."""
+    from repro.core.equilibrium_batch import ROW_BLOCK, BatchPlanner
+    from repro.core.shard import ShardedBatchPlanner
+    state = small_test_cluster()
+    for cls in (BatchPlanner, ShardedBatchPlanner):
+        for row_block, rb in ((None, ROW_BLOCK), (8, 8), (13, 13)):
+            bp = cls(state, row_block=row_block)
+            bp.sync()
+            assert bp._rb == rb and bp._r_cap % rb == 0
 
 
 # ---------------------------------------------------------------------------
@@ -588,3 +622,37 @@ def test_hybrid_moves_identical_with_the_tracer_on_and_off(tracer):
             on = create_planner("equilibrium_batch").plan(
                 _hybrid_small_cluster())
     assert off.moves and as_tuples(on.moves) == as_tuples(off.moves)
+
+
+def test_walk_steps_counter_pins_cluster_d(monkeypatch):
+    """``batch.walk_steps`` folds, once per plan, the walk's iterations
+    that each chunk returns beside its moves (one count per scan step):
+    on cluster D's cold plan it is their exact sum, and the same count as
+    the ``batch.tiles_walked`` device counter."""
+    from repro import obs
+    from repro.core import equilibrium_batch as eb
+    from repro.core.clustergen import cluster_d
+    from repro.core.planner import create_planner
+    fetched = []
+    fetch = eb._fetch
+
+    def spy(tree, what):
+        out = fetch(tree, what)
+        if what == "chunk":
+            fetched.append(np.asarray(out[1]))
+        return out
+
+    monkeypatch.setattr(eb, "_fetch", spy)
+    reg = obs.registry()
+    snap = reg.snapshot()
+    moves = create_planner("equilibrium_batch").plan(cluster_d()).moves
+    steps = reg.deltas_since(snap)["batch.walk_steps"]
+    per_step = np.concatenate(fetched)
+    assert len(moves) == 333
+    assert steps == int(per_step.sum()) == 1650
+    assert (per_step[:len(moves)] >= 1).all()
+    snap = reg.snapshot()
+    with obs.tracing(device_counters=True):
+        create_planner("equilibrium_batch").plan(cluster_d())
+    d = reg.deltas_since(snap)
+    assert d["batch.walk_steps"] == d["batch.tiles_walked"] == steps
